@@ -53,21 +53,28 @@ with mesh, use_rules(rules.activation_rules(mesh)):
 
 
 def run():
+    failed = []
     for ndev in (2, 4, 8):
+        # the children lower for fake host devices: pinned to the CPU so
+        # they never contend for an accelerator the parent holds
         env = {**os.environ,
                "XLA_FLAGS": f"--xla_force_host_platform_device_count={ndev}",
+               "JAX_PLATFORMS": "cpu",
                "PYTHONPATH": "src"}
         r = subprocess.run(
             [sys.executable, "-c", textwrap.dedent(_CODE.format(ndev=ndev))],
             capture_output=True, text=True, env=env)
         if r.returncode != 0:
             emit(f"fig10_dp{ndev}", 0.0, f"ERROR:{r.stderr[-120:]}")
+            failed.append(ndev)
             continue
         data = json.loads(r.stdout.strip().splitlines()[-1])
         emit(f"fig10_dp{ndev}_collective", 0.0,
              f"{data['coll'] / 1e6:.1f}MB/dev/step")
         emit(f"fig10_dp{ndev}_flops", 0.0,
              f"{(data['flops'] or 0) / 1e9:.1f}GFLOP/dev/step")
+    if failed:
+        raise RuntimeError(f"lowering failed on {failed} host devices")
 
 
 if __name__ == "__main__":

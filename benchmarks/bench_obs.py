@@ -91,7 +91,7 @@ def _run():
     tracer = obs.Tracer()
     a = jnp.asarray(rng.normal(size=(128, 128)), f32)
     b = jnp.asarray(rng.normal(size=(128, 128)), f32)
-    with repro.use(backend="pallas", interpret=True,
+    with repro.use(backend="pallas",
                    blocks_policy="autotune", tracer=tracer):
         jax.block_until_ready(matmul(a, b))
     measures = tracer.spans("autotune.measure")

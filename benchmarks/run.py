@@ -51,6 +51,7 @@ def main() -> None:
     import jax
 
     import repro
+    from repro.runtime.compile_cache import enable_compile_cache
     from benchmarks import (bench_attention, bench_autotune, bench_brgemm,
                             bench_conv_resnet50, bench_conv_strategies,
                             bench_distributed_proxy, bench_fc, bench_lstm,
@@ -73,6 +74,7 @@ def main() -> None:
             ap.error(f"--mesh runs inside bench_autotune, which --only "
                      f"{args.only!r} filtered out (use --only autotune)")
 
+    enable_compile_cache()
     print("name,us_per_call,derived")
     ok = True
     # use(backend=None, ...) leaves every field unset — a no-op context.

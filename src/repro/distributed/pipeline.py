@@ -17,7 +17,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -73,9 +72,9 @@ def pipeline_apply(stage_params, x, layer_fn, *, mesh, n_microbatches: int,
         return jax.lax.psum(outputs, axis)
 
     spec_params = jax.tree.map(lambda _: P(axis), stage_params)
-    f = shard_map(
+    f = jax.shard_map(
         stage_body, mesh=mesh,
         in_specs=(spec_params, P()),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     return f(stage_params, x)

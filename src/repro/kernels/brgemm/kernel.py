@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import pallas_compat as _pc
 from repro.core import fusion
 from repro.core.blocking import Blocks, choose_blocks, round_up
 
@@ -174,7 +173,7 @@ def matmul_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, r: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
-        compiler_params=_pc.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -247,7 +246,7 @@ def brgemm_stacked_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, r: (i, j)),
         out_shape=jax.ShapeDtypeStruct((ap.shape[1], bp.shape[2]), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
-        compiler_params=_pc.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -350,7 +349,7 @@ def batched_matmul_pallas(
         out_specs=pl.BlockSpec((1, bm, bn), lambda bi, i, j, r: (bi, i, j)),
         out_shape=jax.ShapeDtypeStruct((nb, mp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
-        compiler_params=_pc.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -20,7 +20,10 @@ The (m, n, k) each entry point reports to ``resolve_blocks`` is the
 *global* problem it was called with — under ``repro.use(mesh=...)``
 dispatch maps it to the per-device local shard before tuning, so the same
 call site gets global-shape tiles in single-device runs and per-shard
-tiles under a production mesh with no threading here.
+tiles under a production mesh with no threading here.  Under a concrete
+multi-device mesh the Pallas kernel then runs on exactly that shard, inside
+``jax.shard_map``: rows on the data axes, the out dim on the model axis,
+and the contraction operands gathered first.
 
 The custom VJP expresses the backward passes through the *same* building
 block, mirroring the paper's claim that fwd/bwd/upd all reduce to
@@ -35,6 +38,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core import dispatch, fusion
 from repro.core.blocking import Blocks
@@ -45,6 +49,7 @@ from repro.core.dispatch import (  # noqa: F401  (deprecated shims, re-exported)
 from repro.kernels.brgemm import kernel as K
 from repro.kernels.brgemm import quant as Q
 from repro.kernels.brgemm import ref as R
+from repro.sharding import local as _local
 
 
 class _Cfg(NamedTuple):
@@ -126,7 +131,12 @@ def _matmul_pallas_backend(x, w, bias, c0, *, activation, alpha, beta,
     n = w.shape[-1]
     cfg = _make_cfg("matmul", m, n, k, x.dtype, activation, alpha, beta,
                     out_dtype, blocks)
-    return _matmul_p(cfg, x, w, bias, c0)
+    fn = _local.per_shard_gemm(
+        functools.partial(_matmul_p, cfg), "matmul", m, n, k,
+        dispatch.current_context(),
+        lambda am, an: ((P(am, None), P(None, an), P(an), P(am, an)),
+                        P(am, an)))
+    return fn(x, w, bias, c0)
 
 
 @dispatch.register("matmul", "xla")
@@ -237,7 +247,12 @@ def _brgemm_pallas_backend(a, b, bias, c0, *, activation, alpha, beta,
     n = b.shape[-1]
     cfg = _make_cfg("brgemm", m, n, k, a.dtype, activation, alpha, beta,
                     out_dtype, blocks)
-    return _brgemm_p(cfg, a, b, bias, c0)
+    fn = _local.per_shard_gemm(
+        functools.partial(_brgemm_p, cfg), "brgemm", m, n, k,
+        dispatch.current_context(),
+        lambda am, an: ((P(None, am, None), P(None, None, an), P(an),
+                         P(am, an)), P(am, an)))
+    return fn(a, b, bias, c0)
 
 
 @dispatch.register("brgemm", "xla")
@@ -290,11 +305,18 @@ def _batched_matmul_pallas_backend(a, b, bias, *, activation, alpha,
     n = b.shape[-1]
     blk = dispatch.resolve_blocks("batched_matmul", m, n, k, a.dtype,
                                   backend="pallas", blocks=blocks)
-    return K.batched_matmul_pallas(
-        a, b, bias, activation=activation, alpha=float(alpha),
+    fn = functools.partial(
+        K.batched_matmul_pallas, activation=activation, alpha=float(alpha),
         out_dtype=out_dtype, blocks=blk,
         interpret=dispatch.resolve_interpret(),
         acc_dtype=dispatch.resolve_accum_dtype())
+    # either operand may be a 2-D one broadcast over the batch
+    fn = _local.per_shard_gemm(
+        fn, "batched_matmul", m, n, k, dispatch.current_context(),
+        lambda am, an: ((P(*(None,) * (a.ndim - 2), am, None),
+                         P(*(None,) * (b.ndim - 1), an), P(an)),
+                        P(None, am, an)))
+    return fn(a, b, bias)
 
 
 @dispatch.register("batched_matmul", "xla")

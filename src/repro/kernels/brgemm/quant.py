@@ -29,8 +29,11 @@ support ``c0``/``beta`` accumulation.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core import dispatch, fusion
 from repro.core.quantize import (
@@ -40,6 +43,7 @@ from repro.core.quantize import (
     quantize_weight,
 )
 from repro.kernels.brgemm import quant_kernel as QK
+from repro.sharding import local as _local
 
 
 def active_quant(w, quant=None) -> QuantConfig | None:
@@ -187,10 +191,15 @@ def matmul_q(x, w, bias=None, c0=None, *, activation="none", alpha=1.0,
         blk = dispatch.resolve_blocks("matmul", m, n, k, wq.dtype,
                                       backend="pallas", blocks=blocks,
                                       quant=qcfg)
-        return QK.matmul_q_pallas(
-            xq, wq, sx, sw, bias, activation=activation, alpha=float(alpha),
-            out_dtype=out_dtype, blocks=blk,
-            interpret=dispatch.resolve_interpret())
+        fn = _local.per_shard_gemm(
+            functools.partial(
+                QK.matmul_q_pallas, activation=activation,
+                alpha=float(alpha), out_dtype=out_dtype, blocks=blk,
+                interpret=dispatch.resolve_interpret()),
+            "matmul", m, n, k, dispatch.current_context(),
+            lambda am, an: ((P(am, None), P(None, an), P(am), P(an), P(an)),
+                            P(am, an)))
+        return fn(xq, wq, sx, sw, bias)
     return matmul_q_ref(xq, wq, sx, sw, bias, activation=activation,
                         alpha=alpha, out_dtype=out_dtype, qcfg=qcfg)
 
@@ -234,10 +243,15 @@ def brgemm_q(a, b, bias=None, c0=None, *, activation="none", alpha=1.0,
         blk = dispatch.resolve_blocks("brgemm", m, n, k, bq.dtype,
                                       backend="pallas", blocks=blocks,
                                       quant=qcfg)
-        return QK.brgemm_q_pallas(
-            aq, bq, sa, sb, bias, activation=activation, alpha=float(alpha),
-            out_dtype=out_dtype, blocks=blk,
-            interpret=dispatch.resolve_interpret())
+        fn = _local.per_shard_gemm(
+            functools.partial(
+                QK.brgemm_q_pallas, activation=activation,
+                alpha=float(alpha), out_dtype=out_dtype, blocks=blk,
+                interpret=dispatch.resolve_interpret()),
+            "brgemm", m, n, k, dispatch.current_context(),
+            lambda am, an: ((P(None, am, None), P(None, None, an), P(am),
+                             P(an), P(an)), P(am, an)))
+        return fn(aq, bq, sa, sb, bias)
     return brgemm_q_ref(aq, bq, sa, sb, bias, activation=activation,
                         alpha=alpha, out_dtype=out_dtype, qcfg=qcfg)
 
@@ -291,10 +305,15 @@ def batched_matmul_q(a, b, bias=None, *, activation="none", alpha=1.0,
     blk = dispatch.resolve_blocks("batched_matmul", m, n, k, bq.dtype,
                                   backend="pallas", blocks=blocks,
                                   quant=qcfg)
-    return QK.batched_matmul_q_pallas(
-        aq, bq, sa, sb, bias, activation=activation, alpha=float(alpha),
-        out_dtype=out_dtype, blocks=blk,
-        interpret=dispatch.resolve_interpret())
+    fn = _local.per_shard_gemm(
+        functools.partial(
+            QK.batched_matmul_q_pallas, activation=activation,
+            alpha=float(alpha), out_dtype=out_dtype, blocks=blk,
+            interpret=dispatch.resolve_interpret()),
+        "batched_matmul", m, n, k, dispatch.current_context(),
+        lambda am, an: ((P(None, am, None), P(None, None, an),
+                         P(None, am), P(None, an), P(an)), P(None, am, an)))
+    return fn(aq, bq, sa, sb, bias)
 
 
 def _batched_ref_from_raw(a, b, bias, *, activation, alpha, out_dtype, qcfg):

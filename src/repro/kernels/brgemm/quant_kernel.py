@@ -30,7 +30,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import fusion
-from repro.core import pallas_compat as _pc
 from repro.core.blocking import Blocks, choose_blocks, round_up
 from repro.kernels.brgemm.kernel import _pad2, _pad3
 
@@ -143,7 +142,7 @@ def matmul_q_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, r: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
-        compiler_params=_pc.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -227,7 +226,7 @@ def brgemm_q_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, r: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
-        compiler_params=_pc.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -318,7 +317,7 @@ def batched_matmul_q_pallas(
         out_specs=pl.BlockSpec((1, bm, bn), lambda bi, i, j, r: (bi, i, j)),
         out_shape=jax.ShapeDtypeStruct((nb, mp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
-        compiler_params=_pc.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),

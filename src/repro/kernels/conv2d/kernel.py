@@ -29,7 +29,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import dispatch
-from repro.core import pallas_compat as _pc
 from repro.core import fusion
 from repro.core.blocking import ConvBlocks, ConvGeometry, round_up
 
@@ -156,7 +155,7 @@ def conv2d_pallas(
             (1, 1, bq, bk), lambda ni, kbi, oj, oib, rsc: (ni, oj, oib, kbi)),
         out_shape=jax.ShapeDtypeStruct((n, p, qp, kp), out_dtype),
         scratch_shapes=[pltpu.VMEM((bq, bk), acc_dtype)],
-        compiler_params=_pc.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 "parallel", "parallel", "parallel", "parallel", "arbitrary"),
         ),

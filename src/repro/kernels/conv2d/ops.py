@@ -17,12 +17,15 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core import dispatch, fusion
 from repro.core.blocking import ConvBlocks, ConvGeometry
 from repro.kernels.brgemm import kernel as BK
 from repro.kernels.conv2d import ref as R
 from repro.kernels.conv2d.kernel import conv2d_pallas
+from repro.launch.mesh import dp_axes
+from repro.sharding import local as _local
 
 
 class _Cfg(NamedTuple):
@@ -127,7 +130,24 @@ def _conv2d_pallas_backend(x, w, bias, *, stride, padding, activation,
                                   geometry=ConvGeometry(stride, r_, s_))
     cfg = _Cfg(stride, padding, activation, out_dtype, blk,
                dispatch.resolve_interpret(), dispatch.resolve_accum_dtype())
-    return _conv_p(cfg, x, w, bias)
+    fn = functools.partial(_conv_p, cfg)
+    ctx = dispatch.current_context()
+    mesh = _local.kernel_mesh(ctx.mesh)
+    if mesh is not None:
+        # per shard: out channels on the axes the tile was tuned for,
+        # images on the data axes where they divide
+        aq, ac, ak = _local.problem_axes("conv2d", q, c, k, mesh,
+                                         ctx.axis_specs)
+        if aq or ac:
+            raise NotImplementedError(
+                "conv2d: the Pallas kernel cannot run with its pixel or "
+                "input-channel dim sharded; pin it to the xla backend")
+        dp = dp_axes(mesh)
+        an = dp if _local.shard_count(n, dp, mesh) > 1 else None
+        fn = _local.per_shard(
+            fn, mesh, (P(an, None, None, None), P(None, None, None, ak),
+                       P(ak)), P(an, None, None, ak))
+    return fn(x, w, bias)
 
 
 @dispatch.register("conv2d", "xla")

@@ -37,7 +37,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import dispatch
-from repro.core import pallas_compat as _pc
 from repro.core.blocking import AttnBwdBlocks, round_up
 from repro.kernels.flash_attention.kernel import STATS_LANES
 
@@ -99,7 +98,7 @@ def delta_rowsum_pallas(y, dy, *, block_q: int = 128,
                                lambda b_, h, i: (b_, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, hq, tqp, STATS_LANES),
                                        jnp.float32),
-        compiler_params=_pc.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
         ),
         interpret=interpret,
@@ -259,7 +258,7 @@ def flash_attention_bwd_pallas(
             pltpu.VMEM((bq, dp), acc_dtype),
             pltpu.VMEM((bq, STATS_LANES), jnp.float32),
         ],
-        compiler_params=_pc.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -319,7 +318,7 @@ def flash_attention_bwd_pallas(
             pltpu.VMEM((bk, dp), acc_dtype),
             pltpu.VMEM((bk, dp), acc_dtype),
         ],
-        compiler_params=_pc.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),

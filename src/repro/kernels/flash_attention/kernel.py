@@ -22,7 +22,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import dispatch
-from repro.core import pallas_compat as _pc
 from repro.core.blocking import AttnBlocks, round_up
 
 NEG_INF = -1e30
@@ -175,7 +174,7 @@ def flash_attention_pallas(
             pltpu.VMEM((bq, STATS_LANES), jnp.float32),
             pltpu.VMEM((bq, STATS_LANES), jnp.float32),
         ],
-        compiler_params=_pc.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),

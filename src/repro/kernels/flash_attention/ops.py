@@ -21,12 +21,15 @@ import warnings
 from typing import NamedTuple
 
 import jax
+from jax.sharding import PartitionSpec as P
 
 from repro.core import dispatch
 from repro.core.blocking import AttnBlocks, AttnBwdBlocks
 from repro.kernels.flash_attention import ref as R
 from repro.kernels.flash_attention.bwd import flash_attention_bwd_pallas
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
+from repro.launch.mesh import dp_axes
+from repro.sharding import local as _local
 
 
 class _Cfg(NamedTuple):
@@ -85,6 +88,32 @@ def _flash_bwd(cfg, res, dy):
 _flash_p.defvjp(_flash_fwd, _flash_bwd)
 
 
+def _attention_spec(q, k):
+    """``(mesh, spec)`` the kernel runs per shard on, or ``None`` off a
+    multi-device mesh: batch on the data axes and heads on the model axis,
+    each where it divides (query and KV heads both, so every shard keeps
+    whole GQA groups).  The (tq, tk, d) triple stays whole, as
+    ``sharding.local.default_axis_specs`` tunes it; a sequence-sharded
+    override would need a ring exchange, so it is refused."""
+    ctx = dispatch.current_context()
+    mesh = _local.kernel_mesh(ctx.mesh)
+    if mesh is None:
+        return None
+    b, hq, tq, d = q.shape
+    hkv, tk = k.shape[1], k.shape[2]
+    if any(_local.problem_axes("flash_attention", tq, tk, d, mesh,
+                               ctx.axis_specs)):
+        raise NotImplementedError(
+            "flash_attention: the Pallas kernel cannot run with its "
+            "(tq, tk, d) sharded; pin it to the xla backend instead")
+    dp = dp_axes(mesh)
+    b_ax = dp if _local.shard_count(b, dp, mesh) > 1 else None
+    h_ax = ("model" if "model" in mesh.axis_names
+            and _local.shard_count(hq, "model", mesh) > 1
+            and _local.shard_count(hkv, "model", mesh) > 1 else None)
+    return mesh, P(b_ax, h_ax, None, None)
+
+
 @dispatch.register("flash_attention", "pallas",
                    available=dispatch.pallas_available, priority=10)
 def _flash_pallas_backend(q, k, v, *, causal, window, scale, xla_impl,
@@ -96,7 +125,12 @@ def _flash_pallas_backend(q, k, v, *, causal, window, scale, xla_impl,
                                   backend="pallas", blocks=blocks)
     cfg = _Cfg(causal, window, scale, blk, blocks_bwd,
                dispatch.resolve_interpret(), dispatch.resolve_accum_dtype())
-    return _flash_p(cfg, q, k, v)
+    fn = functools.partial(_flash_p, cfg)
+    shard = _attention_spec(q, k)
+    if shard is not None:
+        mesh, spec = shard
+        fn = _local.per_shard(fn, mesh, (spec, spec, spec), spec)
+    return fn(q, k, v)
 
 
 @dispatch.register("flash_attention", "xla")
@@ -117,10 +151,16 @@ def _flash_xla_backend(q, k, v, *, causal, window, scale, xla_impl, unroll,
                    available=dispatch.pallas_available, priority=10)
 def _flash_bwd_pallas_backend(q, k, v, y, lse, dy, *, causal, window, scale,
                               blocks):
-    return _fused_bwd(q, k, v, y, lse, dy, causal=causal, window=window,
-                      scale=scale, blocks=blocks,
-                      interpret=dispatch.resolve_interpret(),
-                      acc_dtype=dispatch.resolve_accum_dtype())
+    fn = functools.partial(
+        _fused_bwd, causal=causal, window=window, scale=scale, blocks=blocks,
+        interpret=dispatch.resolve_interpret(),
+        acc_dtype=dispatch.resolve_accum_dtype())
+    shard = _attention_spec(q, k)
+    if shard is not None:
+        mesh, spec = shard
+        fn = _local.per_shard(fn, mesh, (spec,) * 4 + (P(*spec[:3]), spec),
+                              (spec, spec, spec))
+    return fn(q, k, v, y, lse, dy)
 
 
 @dispatch.register("flash_attention_bwd", "xla")

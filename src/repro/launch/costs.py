@@ -1,7 +1,3 @@
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
-
-# isort: split
 """Exact HLO cost accounting via layer-count extrapolation.
 
 XLA's cost analysis counts a ``lax.scan`` (while-loop) body ONCE regardless
@@ -26,12 +22,13 @@ Usage:
 """
 import argparse
 import json
+import os
 import subprocess
 import sys
 
 from repro import configs
 from repro.configs.shapes import SHAPES, applicable
-from repro.launch.dryrun import ART, lower_cell
+from repro.launch.dryrun import ART, force_host_device_count, lower_cell
 
 _FIELDS = ("flops_per_device", "bytes_accessed_per_device")
 
@@ -156,6 +153,7 @@ def main():
     ap.add_argument("--set", dest="overrides", action="append", default=[],
                     help="ArchCfg override, e.g. --set attention_impl=chunked")
     args = ap.parse_args()
+    force_host_device_count()
     ART.mkdir(parents=True, exist_ok=True)
 
     overrides = {}
@@ -179,7 +177,9 @@ def main():
                 if args.multi_pod:
                     cmd.append("--multi-pod")
                 print(f"[costs] {arch} x {shape}", flush=True)
-                r = subprocess.run(cmd, capture_output=True, text=True)
+                r = subprocess.run(
+                    cmd, capture_output=True, text=True,
+                    env={**os.environ, "JAX_PLATFORMS": "cpu"})
                 if r.returncode != 0:
                     n_err += 1
                     print(f"  ERROR: {r.stderr[-400:]}", flush=True)

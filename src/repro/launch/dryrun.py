@@ -321,7 +321,8 @@ def run_all(multi_pod: bool, force: bool = False):
                 cmd.append("--multi-pod")
             print(f"[dryrun] {arch} x {shape} "
                   f"({'multi' if multi_pod else 'single'})", flush=True)
-            r = subprocess.run(cmd, capture_output=True, text=True)
+            r = subprocess.run(cmd, capture_output=True, text=True,
+                               env={**os.environ, "JAX_PLATFORMS": "cpu"})
             if r.returncode != 0:
                 rec = {"arch": arch, "shape": shape,
                        "mesh": "multi" if multi_pod else "single",

@@ -1,19 +1,34 @@
 """Production meshes (functions, not module constants — importing this
-module never touches jax device state)."""
+module never touches jax device state).
+
+Every mesh here has *Auto* axes: the partitioner propagates shardings from
+the ``sharding.rules`` annotations, which is what those rules were written
+for.  (``jax.make_mesh`` defaults to Explicit axes, under which a gather or
+a ``pallas_call`` must carry its sharding in its type.)  Pallas kernels,
+which no partitioner can split, run per shard under ``jax.shard_map`` in
+the op layer (``repro.sharding.local.kernel_mesh``).
+"""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """Arbitrary mesh with Auto axes, e.g. (2, 2) x (data, model).
+
+    ``devices`` defaults to the visible devices; pass described devices
+    (``jax.experimental.topologies``) to compile for a chip that is not
+    attached."""
+    shape, axes = tuple(shape), tuple(axes)
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape, axes):
-    """Arbitrary mesh for tests/examples (e.g. (2, 2) x (data, model))."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(n_devices: int | None = None):
@@ -29,7 +44,7 @@ def make_host_mesh(n_devices: int | None = None):
     model = 1
     while model * 2 <= min(n // 2, 16) and n % (model * 2) == 0:
         model *= 2
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def dp_axes(mesh) -> tuple[str, ...]:

@@ -18,6 +18,7 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.configs.shapes import ShapeCfg
 from repro.data.pipeline import TokenPipeline
 from repro.launch.mesh import make_mesh
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.sharding import rules
 from repro.sharding.annotate import use_rules
 from repro.train import optimizer as opt
@@ -47,17 +48,17 @@ def run(cfg, shape, *, mesh, steps: int, ckpt_dir=None, save_every=50,
 
         pipe = TokenPipeline(cfg, shape, seed=seed, start_step=start)
         losses = []
-        t0 = time.time()
         for step in range(start, steps):
+            t0 = time.perf_counter()
             batch = next(pipe)
             state, metrics = jit_step(state, batch)
-            losses.append(float(metrics["loss"]))
+            losses.append(float(metrics["loss"]))  # waits for the step
+            dt = time.perf_counter() - t0
             if step % log_every == 0 or step == steps - 1:
-                dt = time.time() - t0
-                tok_s = (step - start + 1) * shape.global_batch \
-                    * batch["tokens"].shape[1] / max(dt, 1e-9)
+                # the first step's time includes tracing and compiling
+                tok_s = batch["tokens"].size / max(dt, 1e-9)
                 print(f"[train] step {step} loss {losses[-1]:.4f} "
-                      f"tokens/s {tok_s:,.0f}")
+                      f"step_s {dt:.3f} tokens/s {tok_s:,.0f}")
             if ckpt and step and step % save_every == 0:
                 ckpt.save_async(step, state)
         if ckpt:
@@ -79,6 +80,7 @@ def main():
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--microbatches", type=int, default=1)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs.get(args.arch)
     if args.reduced:

@@ -78,12 +78,11 @@ def _shmap_over_dp(fn, g_: int):
         size *= mesh.shape[a]
     if not dp or size <= 1 or g_ % size != 0:
         return fn
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     spec = P(dp)
-    return shard_map(fn, mesh=mesh, in_specs=(spec, spec), out_specs=spec,
-                     check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec),
+                         out_specs=spec, check_vma=False)
 
 
 def _route(params, xg, cfg: MoECfg, cap: int, backend):

@@ -21,7 +21,7 @@ no-locking invariant holds no matter how many HTTP clients connect.
 Run a toy server::
 
     PYTHONPATH=src python -m repro.serve.http --arch smollm-135m \
-        --reduced --interpret --port 8080
+        --reduced --port 8080
 """
 from __future__ import annotations
 
@@ -241,6 +241,7 @@ def main(argv=None) -> None:
 
     from repro import configs
     from repro.models import api as model_api
+    from repro.runtime.compile_cache import enable_compile_cache
     from repro.serve.cluster import EngineReplica
     from repro.serve.engine import ContinuousEngine, PoolConfig
 
@@ -249,8 +250,6 @@ def main(argv=None) -> None:
     p.add_argument("--arch", default="smollm-135m")
     p.add_argument("--reduced", action="store_true",
                    help="shrink the config (toy weights)")
-    p.add_argument("--interpret", action="store_true",
-                   help="pallas interpret mode (no accelerator needed)")
     p.add_argument("--replicas", type=int, default=1)
     p.add_argument("--n-slots", type=int, default=4)
     p.add_argument("--max-len", type=int, default=128)
@@ -258,6 +257,7 @@ def main(argv=None) -> None:
     p.add_argument("--port", type=int, default=8080)
     p.add_argument("--verbose", action="store_true")
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     cfg = configs.get(args.arch)
     if args.reduced:
@@ -266,7 +266,7 @@ def main(argv=None) -> None:
     pool = PoolConfig(n_slots=args.n_slots, max_len=args.max_len)
     replicas = [
         EngineReplica(name=f"r{i}", engine=ContinuousEngine(
-            cfg, params, pool, interpret=args.interpret or None))
+            cfg, params, pool))
         for i in range(args.replicas)
     ]
     router = EngineRouter(replicas)

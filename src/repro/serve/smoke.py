@@ -66,7 +66,7 @@ def _continuous_smoke(args) -> None:
     decode_quant = "int8" if args.quant else None
     engine = ContinuousEngine(
         cfg, params, PoolConfig(n_slots=args.n_slots, max_len=args.max_len),
-        backend="pallas", blocks_policy="autotune", interpret=True,
+        backend="pallas", blocks_policy="autotune",
         decode_quant=decode_quant)
 
     rng = np.random.default_rng(0)
@@ -270,8 +270,7 @@ def _paged_smoke(args) -> None:
                     for i, p in enumerate(prompts)]
 
     slotted = ContinuousEngine(
-        cfg, params, PoolConfig(n_slots=args.n_slots, max_len=args.max_len),
-        interpret=True)
+        cfg, params, PoolConfig(n_slots=args.n_slots, max_len=args.max_len))
     reference = slotted.serve(reqs())
 
     page_size = 8
@@ -280,8 +279,7 @@ def _paged_smoke(args) -> None:
     engine = ContinuousEngine(
         cfg, params, PoolConfig(n_slots=args.n_slots, max_len=args.max_len,
                                 page_size=page_size, n_pages=n_pages,
-                                prefill_chunk=2 * page_size),
-        interpret=True)
+                                prefill_chunk=2 * page_size))
     if not engine.paged:
         raise SystemExit(f"arch {args.arch} did not take the paged pool")
     out = engine.serve(reqs())

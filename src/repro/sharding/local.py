@@ -20,7 +20,12 @@ device runs.  This module computes the local view:
     e.g. a row-parallel GEMM shards k on the model axis instead of n,
   * :func:`mesh_signature` is the tuning-cache tag: the mesh *axis names*
     (not sizes), so entries tuned per-shard transfer across mesh sizes
-    exactly when the local problems coincide.
+    exactly when the local problems coincide,
+  * :func:`per_shard_gemm` (and, for attention, :func:`kernel_mesh` with
+    :func:`per_shard`) is how the op layer runs a Pallas kernel per shard
+    under ``jax.shard_map``, on the axes :func:`problem_axes` gives — the
+    ones :func:`local_problem` tunes for, so the tiles dispatch picks are
+    the tiles the kernel runs.
 
 Only ``mesh.axis_names`` and ``mesh.shape`` are read, so a real
 ``jax.sharding.Mesh`` and a device-free ``AbstractMesh`` (see
@@ -28,6 +33,8 @@ Only ``mesh.axis_names`` and ``mesh.shape`` are read, so a real
 in dispatch.
 """
 from __future__ import annotations
+
+import jax
 
 from repro.launch.mesh import dp_axes
 
@@ -115,9 +122,11 @@ def default_axis_specs(mesh) -> dict[str, tuple]:
     }
 
 
-def local_problem(op: str, m: int, n: int, k: int, mesh,
-                  axis_specs=None) -> tuple[int, int, int]:
-    """The per-device (m, n, k) of ``op`` under ``mesh``.
+def problem_axes(op: str, m: int, n: int, k: int, mesh,
+                 axis_specs=None) -> tuple:
+    """The mesh axes each of ``op``'s (m, n, k) shards over, after the
+    divisibility fallback: one entry per dim, ``None`` where it stays
+    global.
 
     ``axis_specs`` (a mapping ``{op: (m_axes, n_axes, k_axes)}``) overrides
     the defaults per op — e.g. a row-parallel projection passes
@@ -131,10 +140,70 @@ def local_problem(op: str, m: int, n: int, k: int, mesh,
         axes = entry.get("axes") if isinstance(entry, dict) else entry
         if axes is not None or not isinstance(entry, dict):
             specs[op_name] = axes
-    spec = specs.get(op)
-    if spec is None:
-        return int(m), int(n), int(k)
-    return local_shape((int(m), int(n), int(k)), spec, mesh)
+    spec = tuple(specs.get(op) or ())
+    spec = spec + (None,) * (3 - len(spec))
+    out = []
+    for d, ax in zip((m, n, k), spec):
+        names = ax if isinstance(ax, (tuple, list)) else (ax,)
+        names = tuple(a for a in names if a in mesh.axis_names)
+        out.append(names if shard_count(int(d), names, mesh) > 1 else None)
+    return tuple(out)
+
+
+def local_problem(op: str, m: int, n: int, k: int, mesh,
+                  axis_specs=None) -> tuple[int, int, int]:
+    """The per-device (m, n, k) of ``op`` under ``mesh`` (axes from
+    :func:`problem_axes`)."""
+    axes = problem_axes(op, m, n, k, mesh, axis_specs)
+    return local_shape((int(m), int(n), int(k)), axes, mesh)
+
+
+def kernel_mesh(mesh):
+    """The mesh a Pallas kernel must run per shard on, or ``None``.
+
+    Only a concrete multi-device ``jax.sharding.Mesh`` qualifies: no
+    partitioner can split a Mosaic kernel, so under such a mesh the op
+    layer wraps the kernel in ``jax.shard_map``.  A device-free
+    ``AbstractMesh`` only shapes tile tuning, and one device needs no
+    split.
+    """
+    if isinstance(mesh, jax.sharding.Mesh) and mesh.size > 1:
+        return mesh
+    return None
+
+
+def per_shard_gemm(fn, op: str, m: int, n: int, k: int, ctx, specs):
+    """A GEMM-family kernel call ``fn`` as it runs under the dispatch
+    context ``ctx``: per shard under ``jax.shard_map`` on a multi-device
+    mesh, else ``fn`` itself.
+
+    ``specs(m_axes, n_axes)`` returns ``(in_specs, out_spec)`` for
+    ``fn``'s operands, given the axes :func:`local_problem` localizes the
+    tile for.  The contraction dim stays gathered: a k-sharded override
+    would leave partial sums that the fused epilogue (bias, activation)
+    cannot finish, so it is refused — pin that op to ``xla`` instead.
+    """
+    mesh = kernel_mesh(ctx.mesh)
+    if mesh is None:
+        return fn
+    am, an, ak = problem_axes(op, m, n, k, mesh, ctx.axis_specs)
+    if ak is not None:
+        raise NotImplementedError(
+            f"{op}: the Pallas kernel cannot run with its contraction dim "
+            f"sharded over {ak}; pin this op with axis_specs={{{op!r}: "
+            f"{{'axes': ..., 'backend': 'xla'}}}}")
+    return per_shard(fn, mesh, *specs(am, an))
+
+
+def per_shard(fn, mesh, in_specs, out_specs):
+    """``fn`` under ``jax.shard_map`` on ``mesh``.
+
+    ``check_vma`` is off: the kernels' custom VJPs and ``pallas_call``
+    carry no varying-axes annotations.  The transpose still sums the
+    cotangent of an operand replicated over an axis across that axis
+    (dX over the model axis, dW over the data axes)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def abstract_mesh(shape, axes):
@@ -143,10 +212,6 @@ def abstract_mesh(shape, axes):
     ``jax.sharding.AbstractMesh`` carries only axis names and sizes —
     exactly what this module and the dispatch tuning key read — so tests
     and benchmarks can model a (16, 16) production mesh without 256
-    devices.  Handles both AbstractMesh constructor generations.
+    devices.
     """
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(zip(axes, shape)))     # jax <= 0.4.x
-    except TypeError:
-        return AbstractMesh(tuple(shape), tuple(axes))   # jax >= 0.5
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
