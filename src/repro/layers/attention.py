@@ -15,6 +15,9 @@ Four modes:
   * decode        — one token against a (padded) cache; GQA caches (k, v),
     MLA caches the *compressed* (c_kv, k_rope) and uses the
     absorbed-matmul formulation (the memory win that motivates MLA).
+
+Named scopes (under the caller's ``attention``): ``core`` holds the
+score-softmax-value work on every path, ``kv_write`` the cache writes.
 """
 from __future__ import annotations
 
@@ -96,6 +99,16 @@ def init_cache(cfg: AttnCfg, batch: int, max_len: int, dtype=jnp.float32):
     }
 
 
+@jax.named_scope("kv_write")
+def _write_cache(cache, new: dict, start):
+    """``cache`` with each ``new[name]`` written in at index ``start``."""
+    cache = dict(cache)
+    for name, x in new.items():
+        cache[name] = jax.lax.dynamic_update_slice(
+            cache[name], x.astype(cache[name].dtype), start)
+    return cache
+
+
 def _split_heads(x, n_heads):
     b, t, _ = x.shape
     return x.reshape(b, t, n_heads, -1).transpose(0, 2, 1, 3)  # (B,H,T,dh)
@@ -125,24 +138,21 @@ def _gqa_qkv(params, x, cfg, positions, backend):
 def _gqa_train(params, x, cfg, backend):
     positions = jnp.arange(x.shape[1])
     q, k, v = _gqa_qkv(params, x, cfg, positions, backend)
-    o = flash_attention(q, k, v, causal=True, window=cfg.window,
-                        backend=backend, xla_impl=cfg.xla_impl,
-                        unroll=cfg.unroll)
+    with jax.named_scope("core"):
+        o = flash_attention(q, k, v, causal=True, window=cfg.window,
+                            backend=backend, xla_impl=cfg.xla_impl,
+                            unroll=cfg.unroll)
     return brgemm.matmul(_merge_heads(o), params["wo"], backend=backend)
 
 
 def _gqa_prefill(params, x, cfg, cache, backend):
     positions = jnp.arange(x.shape[1])
     q, k, v = _gqa_qkv(params, x, cfg, positions, backend)
-    o = flash_attention(q, k, v, causal=True, window=cfg.window,
-                        backend=backend, xla_impl=cfg.xla_impl,
-                        unroll=cfg.unroll)
-    t = x.shape[1]
-    cache = dict(cache)
-    cache["k"] = jax.lax.dynamic_update_slice(
-        cache["k"], k.astype(cache["k"].dtype), (0, 0, 0, 0))
-    cache["v"] = jax.lax.dynamic_update_slice(
-        cache["v"], v.astype(cache["v"].dtype), (0, 0, 0, 0))
+    with jax.named_scope("core"):
+        o = flash_attention(q, k, v, causal=True, window=cfg.window,
+                            backend=backend, xla_impl=cfg.xla_impl,
+                            unroll=cfg.unroll)
+    cache = _write_cache(cache, {"k": k, "v": v}, (0, 0, 0, 0))
     y = brgemm.matmul(_merge_heads(o), params["wo"], backend=backend)
     return y, cache
 
@@ -158,13 +168,11 @@ def _gqa_prefill_chunk(params, x, cfg, cache, pos, backend):
     """
     positions = pos + jnp.arange(x.shape[1])
     q, k, v = _gqa_qkv(params, x, cfg, positions, backend)
-    cache = dict(cache)
-    cache["k"] = jax.lax.dynamic_update_slice(
-        cache["k"], k.astype(cache["k"].dtype), (0, 0, pos, 0))
-    cache["v"] = jax.lax.dynamic_update_slice(
-        cache["v"], v.astype(cache["v"].dtype), (0, 0, pos, 0))
-    o = mha_ref(q, cache["k"], cache["v"], causal=True, window=cfg.window,
-                q_offset=pos, kv_len=pos + x.shape[1])
+    cache = _write_cache(cache, {"k": k, "v": v}, (0, 0, pos, 0))
+    with jax.named_scope("core"):
+        o = mha_ref(q, cache["k"], cache["v"], causal=True,
+                    window=cfg.window, q_offset=pos,
+                    kv_len=pos + x.shape[1])
     y = brgemm.matmul(_merge_heads(o), params["wo"], backend=backend)
     return y, cache
 
@@ -172,13 +180,10 @@ def _gqa_prefill_chunk(params, x, cfg, cache, pos, backend):
 def _gqa_decode(params, x, cfg, cache, pos, backend):
     positions = jnp.full((x.shape[1],), pos)
     q, k, v = _gqa_qkv(params, x, cfg, positions, backend)
-    cache = dict(cache)
-    cache["k"] = jax.lax.dynamic_update_slice(
-        cache["k"], k.astype(cache["k"].dtype), (0, 0, pos, 0))
-    cache["v"] = jax.lax.dynamic_update_slice(
-        cache["v"], v.astype(cache["v"].dtype), (0, 0, pos, 0))
-    o = mha_ref(q, cache["k"], cache["v"], causal=False, window=cfg.window,
-                q_offset=pos, kv_len=pos + 1)
+    cache = _write_cache(cache, {"k": k, "v": v}, (0, 0, pos, 0))
+    with jax.named_scope("core"):
+        o = mha_ref(q, cache["k"], cache["v"], causal=False,
+                    window=cfg.window, q_offset=pos, kv_len=pos + 1)
     y = brgemm.matmul(_merge_heads(o), params["wo"], backend=backend)
     return y, cache
 
@@ -228,8 +233,10 @@ def _mla_full(params, x, cfg, backend):
                                   (b, cfg.n_heads, t, cfg.qk_rope_dim))],
         axis=-1)
     scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
-    o = flash_attention(q, k, v, causal=True, scale=scale, backend=backend,
-                        xla_impl=cfg.xla_impl, unroll=cfg.unroll)
+    with jax.named_scope("core"):
+        o = flash_attention(q, k, v, causal=True, scale=scale,
+                            backend=backend, xla_impl=cfg.xla_impl,
+                            unroll=cfg.unroll)
     y = brgemm.matmul(_merge_heads(o), params["wo"], backend=backend)
     return y, c_kv, k_rope
 
@@ -241,30 +248,27 @@ def _mla_decode(params, x, cfg, cache, pos, backend):
     q_nope, q_rope = _mla_q(params, x, cfg, positions, backend)
     c_kv_new, k_rope_new = _mla_compressed_kv(params, x, cfg, positions,
                                               backend)
-    cache = dict(cache)
-    cache["c_kv"] = jax.lax.dynamic_update_slice(
-        cache["c_kv"], c_kv_new.astype(cache["c_kv"].dtype), (0, pos, 0))
-    cache["k_rope"] = jax.lax.dynamic_update_slice(
-        cache["k_rope"], k_rope_new.astype(cache["k_rope"].dtype),
-        (0, pos, 0))
+    cache = _write_cache(cache, {"c_kv": c_kv_new, "k_rope": k_rope_new},
+                         (0, pos, 0))
 
     wkv_b = params["wkv_b"].reshape(
         cfg.kv_lora_rank, cfg.n_heads, cfg.qk_nope_dim + cfg.v_head_dim)
     w_uk = wkv_b[..., :cfg.qk_nope_dim]    # (L, H, nope)
     w_uv = wkv_b[..., cfg.qk_nope_dim:]    # (L, H, v)
 
-    q_eff = jnp.einsum("bhqn,lhn->bhql", q_nope, w_uk)
-    s = (jnp.einsum("bhql,bsl->bhqs", q_eff, cache["c_kv"],
-                    preferred_element_type=jnp.float32)
-         + jnp.einsum("bhqr,bsr->bhqs", q_rope, cache["k_rope"],
-                      preferred_element_type=jnp.float32))
-    s = s * (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
-    kv_len = pos + 1
-    mask = jnp.arange(cache["c_kv"].shape[1])[None, None, None] < kv_len
-    s = jnp.where(mask, s, -1e30)
-    p = jax.nn.softmax(s, axis=-1).astype(x.dtype)
-    o_c = jnp.einsum("bhqs,bsl->bhql", p, cache["c_kv"])
-    o = jnp.einsum("bhql,lhv->bhqv", o_c, w_uv)
+    with jax.named_scope("core"):
+        q_eff = jnp.einsum("bhqn,lhn->bhql", q_nope, w_uk)
+        s = (jnp.einsum("bhql,bsl->bhqs", q_eff, cache["c_kv"],
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("bhqr,bsr->bhqs", q_rope, cache["k_rope"],
+                          preferred_element_type=jnp.float32))
+        s = s * (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+        kv_len = pos + 1
+        mask = jnp.arange(cache["c_kv"].shape[1])[None, None, None] < kv_len
+        s = jnp.where(mask, s, -1e30)
+        p = jax.nn.softmax(s, axis=-1).astype(x.dtype)
+        o_c = jnp.einsum("bhqs,bsl->bhql", p, cache["c_kv"])
+        o = jnp.einsum("bhql,lhv->bhqv", o_c, w_uv)
     y = brgemm.matmul(_merge_heads(o), params["wo"], backend=backend)
     return y, cache
 
@@ -281,31 +285,28 @@ def _mla_prefill_chunk(params, x, cfg, cache, pos, backend):
     q_nope, q_rope = _mla_q(params, x, cfg, positions, backend)
     c_kv_new, k_rope_new = _mla_compressed_kv(params, x, cfg, positions,
                                               backend)
-    cache = dict(cache)
-    cache["c_kv"] = jax.lax.dynamic_update_slice(
-        cache["c_kv"], c_kv_new.astype(cache["c_kv"].dtype), (0, pos, 0))
-    cache["k_rope"] = jax.lax.dynamic_update_slice(
-        cache["k_rope"], k_rope_new.astype(cache["k_rope"].dtype),
-        (0, pos, 0))
+    cache = _write_cache(cache, {"c_kv": c_kv_new, "k_rope": k_rope_new},
+                         (0, pos, 0))
 
     wkv_b = params["wkv_b"].reshape(
         cfg.kv_lora_rank, cfg.n_heads, cfg.qk_nope_dim + cfg.v_head_dim)
     w_uk = wkv_b[..., :cfg.qk_nope_dim]
     w_uv = wkv_b[..., cfg.qk_nope_dim:]
 
-    q_eff = jnp.einsum("bhqn,lhn->bhql", q_nope, w_uk)
-    s = (jnp.einsum("bhql,bsl->bhqs", q_eff, cache["c_kv"],
-                    preferred_element_type=jnp.float32)
-         + jnp.einsum("bhqr,bsr->bhqs", q_rope, cache["k_rope"],
-                      preferred_element_type=jnp.float32))
-    s = s * (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
-    q_pos = pos + jnp.arange(t)[:, None]                      # (Tq, 1)
-    s_pos = jnp.arange(cache["c_kv"].shape[1])[None, :]       # (1, S)
-    mask = s_pos <= q_pos                                     # causal
-    s = jnp.where(mask[None, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1).astype(x.dtype)
-    o_c = jnp.einsum("bhqs,bsl->bhql", p, cache["c_kv"])
-    o = jnp.einsum("bhql,lhv->bhqv", o_c, w_uv)
+    with jax.named_scope("core"):
+        q_eff = jnp.einsum("bhqn,lhn->bhql", q_nope, w_uk)
+        s = (jnp.einsum("bhql,bsl->bhqs", q_eff, cache["c_kv"],
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("bhqr,bsr->bhqs", q_rope, cache["k_rope"],
+                          preferred_element_type=jnp.float32))
+        s = s * (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+        q_pos = pos + jnp.arange(t)[:, None]                  # (Tq, 1)
+        s_pos = jnp.arange(cache["c_kv"].shape[1])[None, :]   # (1, S)
+        mask = s_pos <= q_pos                                 # causal
+        s = jnp.where(mask[None, None], s, -1e30)
+        p = jax.nn.softmax(s, axis=-1).astype(x.dtype)
+        o_c = jnp.einsum("bhqs,bsl->bhql", p, cache["c_kv"])
+        o = jnp.einsum("bhql,lhv->bhqv", o_c, w_uv)
     y = brgemm.matmul(_merge_heads(o), params["wo"], backend=backend)
     return y, cache
 
@@ -323,13 +324,8 @@ def apply(params, x, cfg: AttnCfg, *, mode: str = "train", cache=None,
             return y
         if mode == "prefill":
             y, c_kv, k_rope = _mla_full(params, x, cfg, backend)
-            cache = dict(cache)
-            cache["c_kv"] = jax.lax.dynamic_update_slice(
-                cache["c_kv"], c_kv.astype(cache["c_kv"].dtype), (0, 0, 0))
-            cache["k_rope"] = jax.lax.dynamic_update_slice(
-                cache["k_rope"], k_rope.astype(cache["k_rope"].dtype),
-                (0, 0, 0))
-            return y, cache
+            return y, _write_cache(cache, {"c_kv": c_kv, "k_rope": k_rope},
+                                   (0, 0, 0))
         if mode == "prefill_chunk":
             return _mla_prefill_chunk(params, x, cfg, cache, pos, backend)
         if mode == "decode":

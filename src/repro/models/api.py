@@ -225,6 +225,9 @@ def decode_step_paged(params, tokens, cfg: ArchCfg, data, page_tables,
     (``view_dtypes`` gives each leaf's compute dtype); dequant happens in
     the gather and fresh scales are computed in the scatter.  Returns
     (logits (S, V), new data, new scales).
+
+    The gather runs under the named scope ``kv_gather``, the split back
+    into pages and the pool scatter under ``kv_scatter``.
     """
     data_leaves, treedef = jax.tree.flatten(data)
     a_leaves = treedef.flatten_up_to(batch_axes)
@@ -238,24 +241,28 @@ def decode_step_paged(params, tokens, cfg: ArchCfg, data, page_tables,
         scale_it = iter(scales or ())
         dtype_it = iter(view_dtypes or ())
         view_leaves = []
-        for x, a, t in zip(data_leaves, a_leaves, t_leaves):
-            if t == -1:
-                view_leaves.append(jnp.expand_dims(next(res_it), a))
-                continue
-            ids = jnp.clip(pt, 0, x.shape[a] - 1)
-            pages = jnp.take(x, ids, axis=a)
-            if quant:
-                pages = _dequant_pages(pages, jnp.take(next(scale_it), ids),
-                                       a, next(dtype_it))
-            view_leaves.append(pages_to_view(pages, a, t))
+        with jax.named_scope("kv_gather"):
+            for x, a, t in zip(data_leaves, a_leaves, t_leaves):
+                if t == -1:
+                    view_leaves.append(jnp.expand_dims(next(res_it), a))
+                    continue
+                ids = jnp.clip(pt, 0, x.shape[a] - 1)
+                pages = jnp.take(x, ids, axis=a)
+                if quant:
+                    pages = _dequant_pages(
+                        pages, jnp.take(next(scale_it), ids), a,
+                        next(dtype_it))
+                view_leaves.append(pages_to_view(pages, a, t))
         view = jax.tree.unflatten(treedef, view_leaves)
         logits, new = decode_step(params, tok[None, :], cfg, view, pos, **kw)
         out_pages, out_res = [], []
-        for x, a, t in zip(treedef.flatten_up_to(new), a_leaves, t_leaves):
-            if t == -1:
-                out_res.append(jnp.squeeze(x, a))
-            else:
-                out_pages.append(view_to_pages(x, a, t, page_size))
+        with jax.named_scope("kv_scatter"):
+            for x, a, t in zip(treedef.flatten_up_to(new), a_leaves,
+                               t_leaves):
+                if t == -1:
+                    out_res.append(jnp.squeeze(x, a))
+                else:
+                    out_pages.append(view_to_pages(x, a, t, page_size))
         return logits[0], tuple(out_pages), tuple(out_res)
 
     logits, pages_upd, res_upd = jax.vmap(
@@ -266,19 +273,21 @@ def decode_step_paged(params, tokens, cfg: ArchCfg, data, page_tables,
     new_leaves = list(data_leaves)
     new_scales = list(scales) if quant else None
     pi = ri = 0
-    for i, (x, a, t) in enumerate(zip(data_leaves, a_leaves, t_leaves)):
-        if t == -1:
-            new_leaves[i] = res_upd[ri]
-            ri += 1
-            continue
-        u = jnp.moveaxis(pages_upd[pi], 0, a)       # slot axis next to pages
-        u = u.reshape(u.shape[:a] + (-1,) + u.shape[a + 2:])
-        if quant:
-            u, sc = _quant_pages(u, a)
-            new_scales[pi] = new_scales[pi].at[flat_ids].set(sc, mode="drop")
-        idx = (slice(None),) * a + (flat_ids,)
-        new_leaves[i] = x.at[idx].set(u.astype(x.dtype), mode="drop")
-        pi += 1
+    with jax.named_scope("kv_scatter"):
+        for i, (x, a, t) in enumerate(zip(data_leaves, a_leaves, t_leaves)):
+            if t == -1:
+                new_leaves[i] = res_upd[ri]
+                ri += 1
+                continue
+            u = jnp.moveaxis(pages_upd[pi], 0, a)   # slot axis next to pages
+            u = u.reshape(u.shape[:a] + (-1,) + u.shape[a + 2:])
+            if quant:
+                u, sc = _quant_pages(u, a)
+                new_scales[pi] = new_scales[pi].at[flat_ids].set(
+                    sc, mode="drop")
+            idx = (slice(None),) * a + (flat_ids,)
+            new_leaves[i] = x.at[idx].set(u.astype(x.dtype), mode="drop")
+            pi += 1
     new_data = jax.tree.unflatten(treedef, new_leaves)
     if quant:
         return logits, new_data, tuple(new_scales)

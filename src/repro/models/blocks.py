@@ -61,13 +61,31 @@ def decoder_block_init(key, cfg: ArchCfg, *, use_moe: bool):
 
 def decoder_block_apply(params, x, cfg: ArchCfg, *, mode="train",
                         cache=None, pos=0, backend=None):
+    with jax.named_scope("attention"):
+        x, new_cache = _attention_sublayer(params, x, cfg, mode=mode,
+                                           cache=cache, pos=pos,
+                                           backend=backend)
+    with jax.named_scope("mlp"):
+        h = norms.rmsnorm(params["ln2"], x)
+        if "moe" in params:
+            y, aux = moe.apply(params["moe"], h, moe_cfg(cfg),
+                               backend=backend)
+        else:
+            y = mlp.apply(params["mlp"], h, activation=cfg.mlp_activation,
+                          backend=backend)
+            aux = ZERO_AUX
+        return x + y, new_cache, aux
+
+
+def _attention_sublayer(params, x, cfg: ArchCfg, *, mode, cache, pos,
+                        backend):
+    """x + attn(ln(x)) and the updated cache."""
     acfg = attn_cfg(cfg)
     h = norms.rmsnorm(params["ln1"], x)
     if mode == "train":
-        x = x + attention.apply(params["attn"], h, acfg, mode="train",
-                                backend=backend)
-        new_cache = cache
-    elif cfg.window and not cfg.mla:
+        return x + attention.apply(params["attn"], h, acfg, mode="train",
+                                   backend=backend), cache
+    if cfg.window and not cfg.mla:
         if mode == "prefill_chunk":
             raise ValueError(
                 "chunked prefill is not supported for sliding-window archs "
@@ -81,20 +99,11 @@ def decoder_block_apply(params, x, cfg: ArchCfg, *, mode="train",
                                 backend=backend)
             new_cache = _ring_from_prefill(params["attn"], h, acfg, cache,
                                            backend)
-        x = x + y
-    else:
-        y, new_cache = attention.apply(
-            params["attn"], h, acfg, mode=mode, cache=cache, pos=pos,
-            backend=backend)
-        x = x + y
-    h = norms.rmsnorm(params["ln2"], x)
-    if "moe" in params:
-        y, aux = moe.apply(params["moe"], h, moe_cfg(cfg), backend=backend)
-    else:
-        y = mlp.apply(params["mlp"], h, activation=cfg.mlp_activation,
-                      backend=backend)
-        aux = ZERO_AUX
-    return x + y, new_cache, aux
+        return x + y, new_cache
+    y, new_cache = attention.apply(
+        params["attn"], h, acfg, mode=mode, cache=cache, pos=pos,
+        backend=backend)
+    return x + y, new_cache
 
 
 def decoder_block_cache(cfg: ArchCfg, batch: int, max_len: int):
@@ -184,8 +193,9 @@ def rec_block_apply(params, x, cfg, *, state=None, backend=None):
     y, state = recurrent.rglru_apply(params["rglru"], h, rglru_cfg(cfg),
                                      state=state, backend=backend)
     x = x + y
-    x = x + mlp.apply(params["mlp"], norms.rmsnorm(params["ln2"], x),
-                      activation=cfg.mlp_activation, backend=backend)
+    with jax.named_scope("mlp"):
+        x = x + mlp.apply(params["mlp"], norms.rmsnorm(params["ln2"], x),
+                          activation=cfg.mlp_activation, backend=backend)
     return x, state
 
 
@@ -211,24 +221,26 @@ def local_attn_block_init(key, cfg: ArchCfg):
 def local_attn_block_apply(params, x, cfg, *, mode="train", cache=None,
                            pos=0, backend=None):
     acfg = attn_cfg(cfg)
-    h = norms.rmsnorm(params["ln1"], x)
-    if mode == "train":
-        x = x + attention.apply(params["attn"], h, acfg, mode="train",
+    with jax.named_scope("attention"):
+        h = norms.rmsnorm(params["ln1"], x)
+        if mode == "train":
+            x = x + attention.apply(params["attn"], h, acfg, mode="train",
+                                    backend=backend)
+            new_cache = cache
+        elif mode == "decode":
+            # ring-buffer cache of size window
+            y, new_cache = _ring_decode(params["attn"], h, acfg, cache, pos,
+                                        backend)
+            x = x + y
+        else:  # prefill
+            y = attention.apply(params["attn"], h, acfg, mode="train",
                                 backend=backend)
-        new_cache = cache
-    elif mode == "decode":
-        # ring-buffer cache of size window
-        y, new_cache = _ring_decode(params["attn"], h, acfg, cache, pos,
-                                    backend)
-        x = x + y
-    else:  # prefill
-        y = attention.apply(params["attn"], h, acfg, mode="train",
-                            backend=backend)
-        new_cache = _ring_from_prefill(params["attn"], h, acfg, cache,
-                                       backend)
-        x = x + y
-    x = x + mlp.apply(params["mlp"], norms.rmsnorm(params["ln2"], x),
-                      activation=cfg.mlp_activation, backend=backend)
+            new_cache = _ring_from_prefill(params["attn"], h, acfg, cache,
+                                           backend)
+            x = x + y
+    with jax.named_scope("mlp"):
+        x = x + mlp.apply(params["mlp"], norms.rmsnorm(params["ln2"], x),
+                          activation=cfg.mlp_activation, backend=backend)
     return x, new_cache
 
 
@@ -240,12 +252,14 @@ def _ring_decode(attn_params, h, acfg, cache, pos, backend):
     q, k, v = attention._gqa_qkv(attn_params, h, acfg, positions, backend)
     slot = pos % w
     cache = dict(cache)
-    cache["k"] = jax.lax.dynamic_update_slice(
-        cache["k"], k.astype(cache["k"].dtype), (0, 0, slot, 0))
-    cache["v"] = jax.lax.dynamic_update_slice(
-        cache["v"], v.astype(cache["v"].dtype), (0, 0, slot, 0))
+    with jax.named_scope("kv_write"):
+        cache["k"] = jax.lax.dynamic_update_slice(
+            cache["k"], k.astype(cache["k"].dtype), (0, 0, slot, 0))
+        cache["v"] = jax.lax.dynamic_update_slice(
+            cache["v"], v.astype(cache["v"].dtype), (0, 0, slot, 0))
     kv_len = jnp.minimum(pos + 1, w)
-    o = mha_ref(q, cache["k"], cache["v"], causal=False, kv_len=kv_len)
+    with jax.named_scope("core"):
+        o = mha_ref(q, cache["k"], cache["v"], causal=False, kv_len=kv_len)
     y = brgemm.matmul(attention._merge_heads(o), attn_params["wo"],
                       backend=backend)
     return y, cache
@@ -257,19 +271,20 @@ def _ring_from_prefill(attn_params, h, acfg, cache, backend):
     t = h.shape[1]
     positions = jnp.arange(t)
     _, k, v = attention._gqa_qkv(attn_params, h, acfg, positions, backend)
-    if t >= w:
-        k_last, v_last = k[:, :, -w:], v[:, :, -w:]
-        shift = (t - w) % w
-        k_last = jnp.roll(k_last, shift, axis=2)
-        v_last = jnp.roll(v_last, shift, axis=2)
-        return {"k": k_last.astype(cache["k"].dtype),
-                "v": v_last.astype(cache["v"].dtype)}
-    cache = dict(cache)
-    cache["k"] = jax.lax.dynamic_update_slice(
-        cache["k"], k.astype(cache["k"].dtype), (0, 0, 0, 0))
-    cache["v"] = jax.lax.dynamic_update_slice(
-        cache["v"], v.astype(cache["v"].dtype), (0, 0, 0, 0))
-    return cache
+    with jax.named_scope("kv_write"):
+        if t >= w:
+            k_last, v_last = k[:, :, -w:], v[:, :, -w:]
+            shift = (t - w) % w
+            k_last = jnp.roll(k_last, shift, axis=2)
+            v_last = jnp.roll(v_last, shift, axis=2)
+            return {"k": k_last.astype(cache["k"].dtype),
+                    "v": v_last.astype(cache["v"].dtype)}
+        cache = dict(cache)
+        cache["k"] = jax.lax.dynamic_update_slice(
+            cache["k"], k.astype(cache["k"].dtype), (0, 0, 0, 0))
+        cache["v"] = jax.lax.dynamic_update_slice(
+            cache["v"], v.astype(cache["v"].dtype), (0, 0, 0, 0))
+        return cache
 
 
 def local_attn_block_cache(cfg: ArchCfg, batch: int, max_len: int):

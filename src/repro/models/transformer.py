@@ -350,6 +350,7 @@ def _train_states(cfg: ArchCfg, batch: int):
 # forward / loss / serve
 # ==========================================================================
 
+@jax.named_scope("embed")
 def _embed_inputs(params, batch, cfg: ArchCfg):
     h = embeddings.encode(params["embed"], batch["tokens"]).astype(_dt(cfg))
     if cfg.n_patches:
@@ -361,6 +362,7 @@ def _embed_inputs(params, batch, cfg: ArchCfg):
     return constrain(h, "activation")
 
 
+@jax.named_scope("head")
 def _head(params, h, cfg: ArchCfg):
     h = norms.rmsnorm(params["final_ln"], h)
     if cfg.tie_embeddings:
@@ -388,6 +390,7 @@ def forward(params, batch, cfg: ArchCfg, *, backend=None):
     return logits, aux
 
 
+@jax.named_scope("loss")
 def _xent(logits, labels, mask):
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     ll = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
@@ -459,8 +462,9 @@ def prefill_chunk(params, batch, cfg: ArchCfg, cache, pos, *, length=None,
 
 def decode_step(params, tokens, cfg: ArchCfg, cache, pos, *, backend=None):
     """tokens: (B, 1); pos: traced int. Returns (logits (B, V), cache)."""
-    h = embeddings.encode(params["embed"], tokens).astype(_dt(cfg))
-    h = constrain(h, "activation")
+    with jax.named_scope("embed"):
+        h = embeddings.encode(params["embed"], tokens).astype(_dt(cfg))
+        h = constrain(h, "activation")
     h, _, cache = _run_stacks(params, h, cfg, mode="decode", caches=cache,
                               pos=pos, backend=backend)
     logits = _head(params, h, cfg)

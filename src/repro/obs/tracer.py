@@ -24,6 +24,12 @@ Spans record on *completion* (children before parents in the buffer);
 synthetic spans for intervals that outlive any ``with`` block — e.g. a
 request's life across many engine steps — are added after the fact with
 :meth:`Tracer.add_span` from already-captured timestamps.
+
+Every ``with`` span also opens a ``jax.profiler.TraceAnnotation`` named
+``"repro." + name`` (no attributes; those stay in this buffer), so under
+``jax.profiler.trace`` the program's spans sit on the profiler's clock
+beside the device ops they launched.  Synthetic ``add_span`` records stay
+in the buffer only: the profiler takes no past timestamps.
 """
 from __future__ import annotations
 
@@ -33,6 +39,8 @@ import itertools
 import threading
 import time
 from typing import Any, Callable, Optional
+
+from jax.profiler import TraceAnnotation
 
 DEFAULT_CAPACITY = 65536
 
@@ -67,10 +75,11 @@ class Span:
     """A live span; use as a context manager.  ``set(**attrs)`` attaches
     attributes (inside or after the ``with`` block — the record holds a
     reference to the same dict), ``event()`` fires an instant event
-    parented here."""
+    parented here.  While open it is also the profiler annotation
+    ``repro.<name>``."""
 
     __slots__ = ("_tracer", "name", "attrs", "span_id", "parent_id",
-                 "t0", "t1")
+                 "t0", "t1", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -80,6 +89,7 @@ class Span:
         self.parent_id: Optional[int] = None
         self.t0: Optional[float] = None
         self.t1: Optional[float] = None
+        self._annotation: Optional[TraceAnnotation] = None
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
@@ -94,12 +104,15 @@ class Span:
         self.span_id = next(tr._ids)
         self.parent_id = stack[-1].span_id if stack else None
         stack.append(self)
+        self._annotation = TraceAnnotation("repro." + self.name)
+        self._annotation.__enter__()
         self.t0 = tr.clock()
         return self
 
     def __exit__(self, *exc) -> None:
         tr = self._tracer
         self.t1 = tr.clock()
+        self._annotation.__exit__(None, None, None)
         stack = tr._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -183,7 +196,9 @@ class Tracer:
         """Record a synthetic span from captured timestamps — for
         intervals no ``with`` block can cover (a request's life across
         many engine steps).  Timestamps must come from this tracer's
-        ``clock`` domain."""
+        ``clock`` domain.  The record stays in this buffer: unlike a
+        ``with`` span it never reaches the profiler trace, which takes no
+        past timestamps."""
         rec = SpanRecord(
             name=name, t0=float(t0), t1=float(t1), span_id=next(self._ids),
             parent_id=parent_id, thread=threading.get_ident(), attrs=attrs)

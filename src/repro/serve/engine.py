@@ -502,12 +502,14 @@ class ContinuousEngine:
 
         # first token comes from the prefill logits
         if req.temperature <= 0.0:
-            tok = int(np.asarray(self._greedy(logits))[0])
+            out = self._greedy(logits)
         else:
             self._key, sub = jax.random.split(self._key)
-            tok = int(np.asarray(self._sample(
+            out = self._sample(
                 logits, jnp.full((1,), req.temperature, jnp.float32),
-                jnp.full((1,), req.top_k, jnp.int32), sub))[0])
+                jnp.full((1,), req.top_k, jnp.int32), sub)
+        with obs.span("prefill.wait"):
+            tok = int(np.asarray(out)[0])
         self.metrics.tokens_generated += 1
         # a preempted request re-admits with its tokens folded into the
         # prompt: its TTFT was already recorded at first admission
@@ -590,9 +592,6 @@ class ContinuousEngine:
                          "cache": self.pool.request_cache(),
                          "pos": 0, "first": True,
                          "logits": None, "ready": False}
-        obs.event("engine.prefill_chunk_start", request_id=state.request_id,
-                  trace=state.trace, prompt_len=len(state.request.prompt),
-                  chunk=self.pool_cfg.prefill_chunk)
 
     def _staging_step(self):
         """Advance the in-flight chunked prefill by one chunk (or retry a
@@ -724,7 +723,18 @@ class ContinuousEngine:
         """One scheduler step: admit, batched decode, evict finished.
 
         Returns a list of ``(request_id, token, finished)`` events.
+
+        Under an active tracer the step records the span ``step`` holding
+        ``admit`` (chunked and one-shot prefills, each first token's
+        ``prefill.wait``), ``pages``, ``decode`` (``decode.upload`` of
+        tokens, page tables and positions; ``decode.wait`` for the
+        sampled tokens) and ``emit`` (per-slot bookkeeping, callbacks,
+        evictions).
         """
+        with obs.span("step"):
+            return self._step()
+
+    def _step(self):
         t0 = self._clock()
         self.metrics.steps += 1
         step = self.metrics.steps
@@ -734,6 +744,50 @@ class ContinuousEngine:
                                            depth)
 
         events = []
+        with obs.span("admit"):
+            self._admit_step(events)
+        if self.paged:
+            with obs.span("pages"):
+                self._ensure_pages()
+        active = sorted(self.scheduler.running.items())
+        if active:
+            tr = obs.current_tracer()
+            dspan = (tr.span("decode", step=step, n_active=len(active))
+                     if tr is not None else obs.NULL_SPAN)
+            with dspan:
+                with obs.span("decode.upload"):
+                    tokens = jnp.asarray(self._tokens)[:, None]
+                    if self.paged:
+                        tables = jnp.asarray(self.pool.page_tables)
+                    positions = jnp.asarray(self.pool.positions)
+                if self.paged:
+                    logits, self.pool.data, self.pool.scales = self._decode(
+                        self.params, tokens, self.pool.data,
+                        self.pool.scales, tables, positions)
+                else:
+                    logits, self.pool.cache = self._decode(
+                        self.params, tokens, self.pool.cache, positions)
+                if not np.any(self._temps > 0):
+                    out = self._greedy(logits)
+                else:
+                    self._key, sub = jax.random.split(self._key)
+                    out = self._sample(
+                        logits, jnp.asarray(self._temps),
+                        jnp.asarray(self._topk), sub)
+                with obs.span("decode.wait"):
+                    toks = np.asarray(out)
+            # np.asarray above syncs, so td1 is when every active slot's
+            # token became known
+            td1 = self._clock()
+            with obs.span("emit"):
+                self._emit_decoded(active, toks, step, td1, events)
+        self.metrics.wall_time_s += self._clock() - t0
+        return events
+
+    def _admit_step(self, events: list) -> None:
+        """Advance the in-flight chunked prefill, then admit waiting
+        requests while slots, pages and the step's prefill budget last;
+        appends each first-token event to ``events``."""
         # per-step prefill token budget (prefill_chunk): the in-flight
         # chunked prefill advances first, then one-shot admissions share
         # whatever is left — decodes never stall more than one chunk
@@ -787,54 +841,27 @@ class ContinuousEngine:
             events.append(self._emit(*event))
             spent += n_prompt
 
-        if self.paged:
-            self._ensure_pages()
-        active = sorted(self.scheduler.running.items())
-        if active:
-            tr = obs.current_tracer()
-            dspan = (tr.span("decode", step=step, n_active=len(active))
-                     if tr is not None else obs.NULL_SPAN)
-            td0 = self._clock()
-            with dspan:
-                if self.paged:
-                    logits, self.pool.data, self.pool.scales = self._decode(
-                        self.params, jnp.asarray(self._tokens)[:, None],
-                        self.pool.data, self.pool.scales,
-                        jnp.asarray(self.pool.page_tables),
-                        jnp.asarray(self.pool.positions))
-                else:
-                    logits, self.pool.cache = self._decode(
-                        self.params, jnp.asarray(self._tokens)[:, None],
-                        self.pool.cache, jnp.asarray(self.pool.positions))
-                if not np.any(self._temps > 0):
-                    toks = np.asarray(self._greedy(logits))
-                else:
-                    self._key, sub = jax.random.split(self._key)
-                    toks = np.asarray(self._sample(
-                        logits, jnp.asarray(self._temps),
-                        jnp.asarray(self._topk), sub))
-            # np.asarray above syncs, so td1 - td0 is the real decode
-            # latency every active slot's token paid this step
-            td1 = self._clock()
-            self.metrics.token_latency_hist.observe(td1 - td0,
-                                                    n=len(active))
-            self.metrics.decode_steps += 1
-            self.metrics.slot_steps += len(active)
-            self.metrics.slot_capacity_steps += self.pool.n_slots
-            for slot, state in active:
-                self.pool.positions[slot] += 1
-                self.pool.lengths[slot] += 1
-                tok = int(toks[slot])
-                self.metrics.tokens_generated += 1
-                finished = self.scheduler.record_token(state, tok, step,
-                                                       now=td1)
-                events.append(self._emit(state.request_id, tok, finished))
-                if finished:
-                    self._evict(state)
-                else:
-                    self._tokens[slot] = tok
-        self.metrics.wall_time_s += self._clock() - t0
-        return events
+    def _emit_decoded(self, active, toks, step: int, now: float,
+                      events: list) -> None:
+        """Book a decode step's tokens: counters, each request's gap since
+        its previous token, callbacks and evictions."""
+        self.metrics.decode_steps += 1
+        self.metrics.slot_steps += len(active)
+        self.metrics.slot_capacity_steps += self.pool.n_slots
+        for slot, state in active:
+            self.pool.positions[slot] += 1
+            self.pool.lengths[slot] += 1
+            tok = int(toks[slot])
+            self.metrics.tokens_generated += 1
+            self.metrics.token_latency_hist.observe(
+                now - state.last_token_time)
+            finished = self.scheduler.record_token(state, tok, step,
+                                                   now=now)
+            events.append(self._emit(state.request_id, tok, finished))
+            if finished:
+                self._evict(state)
+            else:
+                self._tokens[slot] = tok
 
     def serve(self, requests, *, key=None) -> dict[int, list[int]]:
         """Run ``requests`` to completion; returns {request_id: token ids}.

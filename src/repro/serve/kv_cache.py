@@ -69,6 +69,7 @@ class SlotKVCache:
         # (deterministic placement for tests and reproducible runs).
         self._free = list(range(n_slots - 1, -1, -1))
 
+        @jax.named_scope("kv_insert")
         def insert(pool, one, slot):
             return jax.tree.map(
                 lambda p, o, a: jax.lax.dynamic_update_slice_in_dim(
@@ -216,6 +217,7 @@ class PagedKVCache:
         page_size_ = page_size
         batch_axes, time_axes = self.batch_axes, self.time_axes
 
+        @jax.named_scope("kv_insert")
         def insert(data, scales, one, slot, page_ids):
             """Scatter a prefilled batch-1 view: pageable leaves split into
             pages and land at ``page_ids``; resident leaves slice in at

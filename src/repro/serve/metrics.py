@@ -139,8 +139,8 @@ class ServeMetrics:
     ttft_count: int = 0
     wall_time_s: float = 0.0
     # latency distributions, engine-observed: wall-clock TTFT per request
-    # and per-token decode-step latency (the batched decode's duration,
-    # one observation per active slot)
+    # and, per decoded token, the gap since the same request's previous
+    # token (one observation per active slot and decode step)
     ttft_hist: LatencyHistogram = dataclasses.field(
         default_factory=LatencyHistogram)
     token_latency_hist: LatencyHistogram = dataclasses.field(
@@ -242,10 +242,12 @@ _PROM_SPEC = (
      "Engine-observed wall-clock TTFT p99 estimate (seconds).",
      lambda m: m.ttft_hist.quantile(0.99)),
     ("token_latency_seconds_p50", "gauge",
-     "Engine-observed per-token decode latency p50 estimate (seconds).",
+     "Engine-observed gap between a request's tokens, p50 estimate "
+     "(seconds).",
      lambda m: m.token_latency_hist.quantile(0.5)),
     ("token_latency_seconds_p99", "gauge",
-     "Engine-observed per-token decode latency p99 estimate (seconds).",
+     "Engine-observed gap between a request's tokens, p99 estimate "
+     "(seconds).",
      lambda m: m.token_latency_hist.quantile(0.99)),
 )
 
@@ -255,7 +257,7 @@ _PROM_HISTOGRAMS = (
     ("ttft_seconds", "Wall-clock time-to-first-token distribution.",
      lambda m: m.ttft_hist),
     ("token_latency_seconds",
-     "Per-token decode-step latency distribution.",
+     "Distribution of the gap between a request's consecutive tokens.",
      lambda m: m.token_latency_hist),
 )
 

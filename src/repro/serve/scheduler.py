@@ -67,6 +67,7 @@ class RequestState:
     admit_time: Optional[float] = None
     prefill_end_time: Optional[float] = None
     finish_time: Optional[float] = None
+    last_token_time: Optional[float] = None   # stamp of the newest token
     trace: Optional[str] = None           # trace id (obs), None untraced
 
     @property
@@ -189,10 +190,10 @@ class Scheduler:
         """Append a generated token; returns True when the request is
         finished (and has been moved out of ``running``)."""
         state.generated.append(int(token))
+        state.last_token_time = time.perf_counter() if now is None else now
         if state.first_token_step is None:
             state.first_token_step = step
-            state.first_token_time = (time.perf_counter()
-                                      if now is None else now)
+            state.first_token_time = state.last_token_time
         reason = None
         if int(token) in state.stop_tokens:
             reason = "stop"

@@ -67,7 +67,8 @@ def make_train_step(cfg: ArchCfg, ocfg: opt.AdamWCfg, *,
                 return _train_step(state, batch)
 
     def _train_step(state, batch):
-        params = opt.cast_params(state["opt"], cfg.dtype)
+        with jax.named_scope("optimizer"):
+            params = opt.cast_params(state["opt"], cfg.dtype)
 
         if microbatches > 1:
             def micro(acc, mb):
@@ -93,9 +94,10 @@ def make_train_step(cfg: ArchCfg, ocfg: opt.AdamWCfg, *,
             grads, scales = compress_grads(grads, kind=grad_compression)
             grads = decompress_grads(grads, scales, kind=grad_compression)
 
-        lr_scale = warmup_cosine(state["opt"]["step"])
-        new_opt, opt_metrics = opt.adamw_update(grads, state["opt"], ocfg,
-                                                lr_scale)
+        with jax.named_scope("optimizer"):
+            lr_scale = warmup_cosine(state["opt"]["step"])
+            new_opt, opt_metrics = opt.adamw_update(grads, state["opt"],
+                                                    ocfg, lr_scale)
         metrics = {**metrics, **opt_metrics}
         return {"opt": new_opt}, metrics
 

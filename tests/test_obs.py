@@ -3,7 +3,9 @@ telemetry + blocks-source classification, unified autotune STATS, FLOP
 accounting, Chrome export round-trip, latency histograms, engine TTFT
 breakdown exactness, and the serve-layer span/event wiring."""
 import asyncio
+import dataclasses
 import json
+import re
 import threading
 from concurrent.futures import ThreadPoolExecutor
 
@@ -584,3 +586,162 @@ def test_request_from_payload_validation():
                 {"prompt": [1], "stop_tokens": "no"}):
         with pytest.raises(ValueError):
             request_from_payload(bad)
+
+
+# ---------------------------------------------------------------------
+# spans in the profiler trace, named scopes in the compiled programs
+# ---------------------------------------------------------------------
+
+def _scope_paths(compiled_text):
+    """Every op's ``op_name`` with transform wrappers (``jvp(...)``,
+    ``transpose(...)``, ``vmap(...)``) unwrapped to what they hold."""
+    out = set()
+    for path in re.findall(r'op_name="([^"]*)"', compiled_text):
+        parts = []
+        for part in path.split("/"):
+            while re.fullmatch(r"\w+\((.*)\)", part):
+                part = re.fullmatch(r"\w+\((.*)\)", part).group(1)
+            parts.append(part)
+        out.add("/".join(parts))
+    return out
+
+
+def _has_scope(paths, scope, *also):
+    return any(f"/{scope}/" in f"/{p}/" and all(a in p for a in also)
+               for p in paths)
+
+
+def _lowered_program(kind, cfg, params):
+    from repro.serve import kv_cache
+    from repro.train import optimizer as opt
+    from repro.train import train_step as ts
+
+    if kind == "train":
+        ocfg = opt.AdamWCfg()
+        state = jax.eval_shape(lambda: ts.init_state(
+            jax.random.PRNGKey(0), cfg, ocfg))
+        batch = {k: jax.ShapeDtypeStruct((2, 32), jnp.int32)
+                 for k in ("tokens", "labels")}
+        step = ts.make_train_step(cfg, ocfg, backend="pallas")
+        return jax.jit(step).lower(state, batch)
+    eng = ContinuousEngine(cfg, params, PoolConfig(
+        n_slots=2, max_len=MAX_LEN, page_size=8, prefill_chunk=8))
+    pool = eng.pool
+    if kind == "decode":
+        return eng._decode.lower(
+            params, jnp.zeros((2, 1), jnp.int32), pool.data, pool.scales,
+            jnp.asarray(pool.page_tables), jnp.asarray(pool.positions))
+    if kind == "chunk":
+        return eng._chunk_rest.lower(
+            params, {"tokens": jnp.zeros((1, 8), jnp.int32)},
+            pool.request_cache(), jnp.int32(8))
+    assert isinstance(pool, kv_cache.PagedKVCache)
+    return pool._insert.lower(pool.data, pool.scales, pool.request_cache(),
+                              jnp.int32(0), jnp.asarray(pool.page_tables[0]))
+
+
+@pytest.mark.parametrize("kind,scopes", [
+    ("decode", ["embed", "attention", "attention/core", "attention/kv_write",
+                "mlp", "head", "kv_gather", "kv_scatter"]),
+    ("chunk", ["embed", "attention", "attention/core", "attention/kv_write",
+               "mlp", "head"]),
+    ("insert", ["kv_insert"]),
+    ("train", ["embed", "attention", "attention/core", "mlp", "head",
+               "loss", "optimizer"]),
+])
+def test_named_scopes_reach_compiled_op_metadata(dense, kind, scopes):
+    cfg, params = dense
+    if kind == "train":    # the Pallas kernels and their custom VJPs
+        cfg = dataclasses.replace(cfg, n_layers=1, remat=True)
+    text = _lowered_program(kind, cfg, params).compile().as_text()
+    paths = _scope_paths(text)
+    for scope in scopes:
+        assert _has_scope(paths, scope), (scope, sorted(paths)[:20])
+    if kind == "train":
+        # backward ops keep the scopes of the forward they transpose:
+        # the fused flash backward and the brgemm's VJP
+        raw = set(re.findall(r'op_name="([^"]*)"', text))
+        assert any("transpose(jvp(" in p and "attention/core" in p
+                   and "flash_attention_bwd" in p for p in raw)
+        assert any("transpose(jvp(" in p and "/mlp/" in p
+                   and "matmul_pallas" in p for p in raw)
+
+
+def _engine_spans_tree(tr):
+    by_id = {s.span_id: s for s in tr.spans()}
+    kids = {}
+    for s in tr.spans():
+        if s.parent_id is not None:
+            kids.setdefault(by_id[s.parent_id].name, set()).add(s.name)
+    return kids
+
+
+def test_engine_step_spans_nest_and_reach_the_profiler(dense, tmp_path):
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    cfg, params = dense
+    eng = ContinuousEngine(cfg, params, PoolConfig(
+        n_slots=2, max_len=MAX_LEN, page_size=8, prefill_chunk=8))
+    reqs = _requests(cfg, 3) + [Request(prompt=list(range(1, 12)),
+                                        max_tokens=3, stop_tokens=())]
+    for r in reqs:
+        eng.submit(r)
+    eng.step()                       # compiles outside the traces
+    tr = obs.Tracer()
+
+    def host_names(log_dir):
+        (path,) = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                            recursive=True)
+        return {e.name for p in ProfileData.from_file(path).planes
+                for line in p.lines for e in line.events
+                if e.name.startswith("repro.")}
+
+    with jax.profiler.trace(str(tmp_path / "off")):
+        eng.step()                   # no tracer: nothing recorded
+    assert host_names(str(tmp_path / "off")) == set()
+    obs.install(tr)
+    try:
+        with jax.profiler.trace(str(tmp_path / "on")):
+            while eng.has_work():
+                eng.step()
+    finally:
+        obs.install(None)
+    kids = _engine_spans_tree(tr)
+    assert {"admit", "pages", "decode", "emit"} <= kids["step"]
+    assert kids["decode"] == {"decode.upload", "decode.wait"}
+    assert "prefill.wait" in kids["admit"]
+    assert tr.spans("step") and all(s.parent_id is None
+                                    for s in tr.spans("step"))
+    names = {s.name for s in tr.spans()}
+    # every with-span reached the profiler; synthetic request spans not
+    assert host_names(str(tmp_path / "on")) == {
+        "repro." + n for n in names if not n.startswith("request")}
+    assert not tr.events("engine.prefill_chunk_start")
+
+
+def test_token_latency_hist_observes_each_requests_token_gap(dense):
+    cfg, params = dense
+    now = [0.0]
+    eng = ContinuousEngine(cfg, params,
+                           PoolConfig(n_slots=2, max_len=MAX_LEN),
+                           clock=lambda: now[0])
+    stamps = {}
+
+    def on_token(rid, tok, finished):
+        stamps.setdefault(rid, []).append(now[0])
+
+    for r in _requests(cfg, 3):      # 3 requests on 2 slots
+        eng.submit(r, on_token=on_token)
+    t = 0.0
+    while eng.has_work():
+        t += 1.5 + t / 4             # uneven steps
+        now[0] = t
+        eng.step()
+    gaps = [b - a for s in stamps.values() for a, b in zip(s, s[1:])]
+    hist = eng.metrics.token_latency_hist
+    assert hist.count == len(gaps) == eng.metrics.slot_steps
+    assert hist.total_s == pytest.approx(sum(gaps))
+    assert sum(gaps) > 0             # not the decode section's length
