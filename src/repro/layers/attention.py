@@ -4,7 +4,7 @@ All projections route through the batch-reduce GEMM building block; the
 attention inner loop uses the flash kernel (itself a batch-reduce GEMM with
 online-softmax epilogue) on the Pallas backend, or the jnp oracle on XLA.
 
-Four modes:
+Five modes:
   * train         — full causal sequence, no cache,
   * prefill       — train-compute + returns the KV cache,
   * prefill_chunk — one chunk of a longer prompt: queries live at absolute
@@ -15,6 +15,9 @@ Four modes:
   * decode        — one token against a (padded) cache; GQA caches (k, v),
     MLA caches the *compressed* (c_kv, k_rope) and uses the
     absorbed-matmul formulation (the memory win that motivates MLA).
+  * decode_paged  — GQA only: one token per slot against a paged pool
+    read in place (``PagedKV``); returns the token's K/V row instead of
+    an updated cache, for the caller to write.
 
 Named scopes (under the caller's ``attention``): ``core`` holds the
 score-softmax-value work on every path, ``kv_write`` the cache writes.
@@ -22,6 +25,7 @@ score-softmax-value work on every path, ``kv_write`` the cache writes.
 from __future__ import annotations
 
 import dataclasses
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +33,7 @@ import jax.numpy as jnp
 from repro.core import brgemm
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.flash_attention.ref import mha_ref
+from repro.kernels.paged_attention import paged_attention
 from repro.layers import norms
 from repro.layers.rope import apply_rope
 
@@ -177,6 +182,32 @@ def _gqa_prefill_chunk(params, x, cfg, cache, pos, backend):
     return y, cache
 
 
+class PagedKV(NamedTuple):
+    """One layer's read-only view of a key-major paged pool: the
+    layer-stacked ``k``/``v`` leaves (L, n_pages, Hkv, d, page_size), the
+    ``layer`` to read, each slot's ``page_tables`` row and ``lengths``
+    (keys held before this token, which is also its position)."""
+    k: jax.Array
+    v: jax.Array
+    layer: jax.Array
+    page_tables: jax.Array
+    lengths: jax.Array
+
+
+def _gqa_decode_paged(params, x, cfg, pages: PagedKV, backend):
+    """x: (S, 1, D), one token per slot at position ``pages.lengths``.
+    Returns (y, {"k", "v"}: the token's (S, Hkv, d) rows)."""
+    q, k, v = _gqa_qkv(params, x, cfg, pages.lengths[:, None], backend)
+    k, v = k[:, :, 0], v[:, :, 0]
+    with jax.named_scope("core"):
+        o = paged_attention(q[:, :, 0], pages.k, pages.v, pages.page_tables,
+                            pages.lengths, k, v, layer=pages.layer,
+                            backend=backend)
+    y = brgemm.matmul(_merge_heads(o[:, :, None]), params["wo"],
+                      backend=backend)
+    return y, {"k": k, "v": v}
+
+
 def _gqa_decode(params, x, cfg, cache, pos, backend):
     positions = jnp.full((x.shape[1],), pos)
     q, k, v = _gqa_qkv(params, x, cfg, positions, backend)
@@ -317,7 +348,8 @@ def _mla_prefill_chunk(params, x, cfg, cache, pos, backend):
 
 def apply(params, x, cfg: AttnCfg, *, mode: str = "train", cache=None,
           pos=0, backend: str | None = None):
-    """x: (B, T, D). Returns y for train, (y, cache) for prefill/decode."""
+    """x: (B, T, D). Returns y for train, (y, cache) for prefill/decode,
+    (y, new K/V rows) for decode_paged."""
     if cfg.mla:
         if mode == "train":
             y, _, _ = _mla_full(params, x, cfg, backend)
@@ -339,4 +371,6 @@ def apply(params, x, cfg: AttnCfg, *, mode: str = "train", cache=None,
         return _gqa_prefill_chunk(params, x, cfg, cache, pos, backend)
     if mode == "decode":
         return _gqa_decode(params, x, cfg, cache, pos, backend)
+    if mode == "decode_paged":
+        return _gqa_decode_paged(params, x, cfg, cache, backend)
     raise ValueError(mode)

@@ -127,7 +127,7 @@ def decode_step_slots(params, tokens, cfg: ArchCfg, cache, positions, *,
 
 
 # --------------------------------------------------------------------------
-# paged decode (page-gather as batch-reduce over page lists)
+# paged decode (batch-reduce over page lists)
 # --------------------------------------------------------------------------
 
 def supports_paging(cfg: ArchCfg) -> bool:
@@ -200,17 +200,97 @@ def _quant_pages(pages, a: int):
     return quantize(pages, "int8", axis=axes)
 
 
+def decodes_in_place(cfg: ArchCfg, time_axes, scales) -> bool:
+    """Whether :func:`decode_step_paged` reads the pool in place.
+
+    True for dense blocks with GQA/MQA ``k``/``v`` leaves and no window,
+    full-precision pages (``scales is None``) and no slot-resident
+    leaves.  Such a pool stores its pages key-major (:func:`key_major`).
+    Every other pool (MLA, MoE, enc-dec, int8 pages) gathers: MoE routing
+    must keep its per-slot groups, and int8 dequant stays in the gather.
+    """
+    return (cfg.block == "dense" and not cfg.mla and not cfg.window
+            and scales is None
+            and all(t != -1 for t in jax.tree.leaves(time_axes)))
+
+
+def key_major(pages, t: int):
+    """Swap the time axis ``t`` of pages with their last axis (its own
+    inverse): the page layout of a pool decoded in place.  With positions
+    on the lanes a page of a head is (d, page_size), aligned to the TPU's
+    tiles for any head size, so the kernel DMAs it as it lies; XLA stores
+    a (page_size, d < 128) page transposed anyway."""
+    return jnp.swapaxes(pages, t, -1)
+
+
 def decode_step_paged(params, tokens, cfg: ArchCfg, data, page_tables,
                       positions, *, batch_axes, time_axes, page_size,
                       scales=None, view_dtypes=None, **kw):
-    """One decode step over a paged pool: gather page lists, batch-reduce.
+    """One decode step over a paged pool.
 
     ``data``: the pool pytree — pageable leaves hold ``n_pages`` pages at
-    their batch axis and ``page_size`` at their time axis; slot-resident
-    leaves (``time_axes`` == -1) hold ``n_slots`` entries at their batch
-    axis.  ``page_tables``: (S, P) int32 page ids, padded with the
-    sentinel ``n_pages`` past each slot's allocation.  ``positions``:
-    (S,) absolute write position per slot.
+    their batch axis and ``page_size`` at their time axis (key-major
+    where :func:`decodes_in_place`); slot-resident leaves (``time_axes``
+    == -1) hold ``n_slots`` entries at their batch axis.
+    ``page_tables``: (S, P) int32 page ids, padded with the sentinel
+    ``n_pages`` past each slot's allocation.  ``positions``: (S,)
+    absolute write position per slot.  ``scales``: with quantized pages,
+    a tuple of (n_pages,) fp32 per-page scale arrays aligned with the
+    pageable leaves in flatten order (``view_dtypes`` gives each leaf's
+    compute dtype).  Returns (logits (S, V), new data, new scales).
+
+    Two paths, chosen by :func:`decodes_in_place`.  In place: one decode
+    at batch S whose attention kernel reads each slot's live pages
+    through its page table, then one scatter of the token's K/V rows
+    (scope ``attention/kv_write``).  Otherwise each slot gathers its
+    page list into a contiguous view and scatters every page back
+    (scopes ``kv_gather``, ``kv_scatter``).
+    """
+    if decodes_in_place(cfg, time_axes, scales):
+        return _decode_in_place(params, tokens, cfg, data, page_tables,
+                                positions, page_size=page_size, **kw)
+    return _decode_gather(params, tokens, cfg, data, page_tables, positions,
+                          batch_axes=batch_axes, time_axes=time_axes,
+                          page_size=page_size, scales=scales,
+                          view_dtypes=view_dtypes, **kw)
+
+
+def _decode_in_place(params, tokens, cfg: ArchCfg, data, page_tables,
+                     positions, *, page_size, **kw):
+    """The in-place path of :func:`decode_step_paged`: the pool is read
+    by the attention kernel and written once, at each slot's position
+    (free slots' sentinel pages drop out)."""
+    kv = data["blocks"]
+    logits, rows = transformer.decode_step_in_place(
+        params, tokens, cfg, kv, page_tables, positions, **kw)
+    with jax.named_scope("attention"), jax.named_scope("kv_write"):
+        page = jnp.take_along_axis(page_tables,
+                                   (positions // page_size)[:, None],
+                                   axis=1, mode="clip")[:, 0]
+        new = {name: _write_rows(x, rows[name], page, positions % page_size)
+               for name, x in kv.items()}
+    return logits, {"blocks": new}, None
+
+
+def _write_rows(pool, rows, page, off):
+    """``pool`` (L, n_pages, Hkv, d, page_size) with ``rows`` (L, S, Hkv,
+    d) written at each slot's (``page``, ``off``); sentinel pages drop.
+
+    Each slot's page is read, its column ``off`` replaced and the whole
+    page written back: a scatter of whole pages keeps the pool's layout,
+    where a scatter of single columns has XLA relayout (copy) the pool.
+    """
+    n_pages, width = pool.shape[1], pool.shape[-1]
+    old = pool[:, jnp.clip(page, 0, n_pages - 1)]     # (L, S, Hkv, d, w)
+    col = jnp.arange(width) == off[:, None, None, None]
+    new = jnp.where(col, rows[..., None].astype(pool.dtype), old)
+    return pool.at[:, page].set(new, mode="drop")
+
+
+def _decode_gather(params, tokens, cfg: ArchCfg, data, page_tables,
+                   positions, *, batch_axes, time_axes, page_size,
+                   scales=None, view_dtypes=None, **kw):
+    """The gather path of :func:`decode_step_paged`.
 
     Per slot (vmapped): gather its page list (sentinels clip to page 0 —
     garbage that ``kv_len`` masking never exposes), reassemble a
@@ -220,14 +300,10 @@ def decode_step_paged(params, tokens, cfg: ArchCfg, data, page_tables,
     ``mode="drop"`` write (sentinel ids fall out), so the whole step stays
     one jit-compiled call.
 
-    ``scales``: with quantized pages, a tuple of (n_pages,) fp32 per-page
-    scale arrays aligned with the pageable leaves in flatten order
-    (``view_dtypes`` gives each leaf's compute dtype); dequant happens in
-    the gather and fresh scales are computed in the scatter.  Returns
-    (logits (S, V), new data, new scales).
-
-    The gather runs under the named scope ``kv_gather``, the split back
-    into pages and the pool scatter under ``kv_scatter``.
+    With quantized pages, dequant happens in the gather and fresh scales
+    are computed in the scatter.  The gather runs under the named scope
+    ``kv_gather``, the split back into pages and the pool scatter under
+    ``kv_scatter``.
     """
     data_leaves, treedef = jax.tree.flatten(data)
     a_leaves = treedef.flatten_up_to(batch_axes)

@@ -10,7 +10,9 @@ stacks scan over *groups* with a fixed per-step structure:
   * rglru_hybrid:     scan over G groups of (rec, rec, attn) + trailing recs
 
 Serve modes (prefill/decode) scan over (params, caches) pairs and emit the
-updated caches as scan outputs.
+updated caches as scan outputs; the in-place paged decode of dense stacks
+scans over params alone, reads the pool as a loop constant and emits only
+each layer's new K/V rows.
 """
 from __future__ import annotations
 
@@ -20,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchCfg
-from repro.layers import embeddings, norms
+from repro.layers import attention, embeddings, norms
 from repro.core import brgemm
 from repro.models import blocks
 from repro.sharding.annotate import constrain
@@ -469,3 +471,34 @@ def decode_step(params, tokens, cfg: ArchCfg, cache, pos, *, backend=None):
                               pos=pos, backend=backend)
     logits = _head(params, h, cfg)
     return logits[:, 0], cache
+
+
+def decode_step_in_place(params, tokens, cfg: ArchCfg, kv, page_tables,
+                         lengths, *, backend=None):
+    """One token per slot against a key-major paged pool read in place
+    (dense stacks only).
+
+    tokens: (S, 1); kv: {"k", "v"} pool leaves (L, n_pages, Hkv, d,
+    page_size), read but not written; page_tables: (S, P); lengths: (S,)
+    keys each slot holds, i.e. its token's position.  The pool enters the
+    layer scan as a constant, never as scanned inputs or outputs (which
+    would restack it).  Returns (logits (S, V), {"k", "v"}: the new rows
+    (L, S, Hkv, d)) for the caller to write into the pool.
+    """
+    with jax.named_scope("embed"):
+        h = embeddings.encode(params["embed"], tokens).astype(_dt(cfg))
+        h = constrain(h, "activation")
+
+    def body(h, xs):
+        p, layer = xs
+        pages = attention.PagedKV(kv["k"], kv["v"], layer, page_tables,
+                                  lengths)
+        h, rows, _ = blocks.decoder_block_apply(
+            p, h, cfg, mode="decode_paged", cache=pages, backend=backend)
+        return h, rows
+
+    h, rows = jax.lax.scan(body, h, (params["blocks"],
+                                     jnp.arange(cfg.n_layers)),
+                           unroll=cfg.scan_unroll)
+    logits = _head(params, h, cfg)
+    return logits[:, 0], rows

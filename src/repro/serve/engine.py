@@ -362,7 +362,14 @@ class ContinuousEngine:
             return jax.jit(_chunk)
 
         self._prefill = jax.jit(_prefill)
-        self._decode = jax.jit(_decode)
+        # the paged decode donates the pool (data, scales): its scatter
+        # then writes the pool where it lies instead of into a copy
+        self._decode = jax.jit(_decode,
+                               donate_argnums=(2, 3) if self.paged else ())
+        # which decode path the pool takes, for the ``decode`` span and
+        # the ``decode_steps_in_place`` counter
+        self.decode_path = (("in_place" if self.pool.in_place else "gather")
+                            if self.paged else "slots")
         if pool.prefill_chunk:
             self._chunk_first = _make_chunk(True)
             self._chunk_rest = _make_chunk(False)
@@ -726,7 +733,8 @@ class ContinuousEngine:
 
         Under an active tracer the step records the span ``step`` holding
         ``admit`` (chunked and one-shot prefills, each first token's
-        ``prefill.wait``), ``pages``, ``decode`` (``decode.upload`` of
+        ``prefill.wait``), ``pages``, ``decode`` (attribute ``path``:
+        ``in_place``, ``gather`` or ``slots``; ``decode.upload`` of
         tokens, page tables and positions; ``decode.wait`` for the
         sampled tokens) and ``emit`` (per-slot bookkeeping, callbacks,
         evictions).
@@ -752,7 +760,8 @@ class ContinuousEngine:
         active = sorted(self.scheduler.running.items())
         if active:
             tr = obs.current_tracer()
-            dspan = (tr.span("decode", step=step, n_active=len(active))
+            dspan = (tr.span("decode", step=step, n_active=len(active),
+                             path=self.decode_path)
                      if tr is not None else obs.NULL_SPAN)
             with dspan:
                 with obs.span("decode.upload"):
@@ -846,6 +855,8 @@ class ContinuousEngine:
         """Book a decode step's tokens: counters, each request's gap since
         its previous token, callbacks and evictions."""
         self.metrics.decode_steps += 1
+        if self.decode_path == "in_place":
+            self.metrics.decode_steps_in_place += 1
         self.metrics.slot_steps += len(active)
         self.metrics.slot_capacity_steps += self.pool.n_slots
         for slot, state in active:

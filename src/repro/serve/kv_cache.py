@@ -23,6 +23,10 @@ anything beyond the live prefix.
 
 With ``kv_quant="int8"`` the paged leaves are stored int8 with one fp32
 absmax scale per page; dequantization is fused into the decode gather.
+A pool that decodes in place (``api.decodes_in_place``: dense GQA,
+full-precision pages) stores its pages key-major (``api.key_major``).
+The paged pool's insert donates ``data`` and ``scales``, as the engine's
+paged decode does: each returns the pool that replaces the one passed.
 """
 from __future__ import annotations
 
@@ -143,6 +147,9 @@ class PagedKVCache:
                  gather, dropped on scatter).
     scales:      with ``kv_quant``, one (n_pages,) fp32 scale array per
                  pageable leaf (flatten order), else None.
+    in_place:    whether decode reads the pool in place
+                 (``api.decodes_in_place``); its pageable leaves then
+                 hold key-major pages (``api.key_major``).
     lengths / positions: as in :class:`SlotKVCache`.
 
     The allocator is host-side and O(1) per op: a slot free-list plus a
@@ -197,6 +204,11 @@ class PagedKVCache:
                 for t in jax.tree.leaves(self.time_axes) if t != -1)
         else:
             self.scales = None
+        self.in_place = api.decodes_in_place(cfg, self.time_axes,
+                                             self.scales)
+        if self.in_place:
+            paged_tmpl = jax.tree.map(api.key_major, paged_tmpl,
+                                      self.time_axes)
         self.data = jax.tree.map(
             lambda pg, res, t: pg if t != -1 else res,
             paged_tmpl, resident_tmpl, self.time_axes)
@@ -214,7 +226,7 @@ class PagedKVCache:
         self._free = list(range(n_slots - 1, -1, -1))
         self._free_pages = list(range(self.n_pages - 1, -1, -1))
 
-        page_size_ = page_size
+        page_size_, in_place = page_size, self.in_place
         batch_axes, time_axes = self.batch_axes, self.time_axes
 
         @jax.named_scope("kv_insert")
@@ -234,6 +246,8 @@ class PagedKVCache:
                         x, o.astype(x.dtype), slot, axis=a))
                     continue
                 pages = api.view_to_pages(o, a, t, page_size_)
+                if in_place:
+                    pages = api.key_major(pages, t)
                 if scales is not None:
                     pages, sc = api._quant_pages(pages, a)
                     new_scales[pi] = new_scales[pi].at[page_ids].set(
@@ -247,7 +261,7 @@ class PagedKVCache:
                 return new_data, None
             return new_data, tuple(new_scales)
 
-        self._insert = jax.jit(insert)
+        self._insert = jax.jit(insert, donate_argnums=(0, 1))
 
     # ---------------- allocator ----------------
 

@@ -122,6 +122,8 @@ class ServeMetrics:
     prefill_chunks: int = 0
     preemptions: int = 0
     decode_steps: int = 0
+    # decode steps whose attention read the paged pool in place
+    decode_steps_in_place: int = 0
     requests_submitted: int = 0
     requests_completed: int = 0
     requests_cancelled: int = 0
@@ -208,6 +210,9 @@ _PROM_SPEC = (
      lambda m: m.preemptions),
     ("decode_steps_total", "counter", "Batched decode steps run.",
      lambda m: m.decode_steps),
+    ("decode_steps_in_place_total", "counter",
+     "Decode steps that read the paged KV pool in place.",
+     lambda m: m.decode_steps_in_place),
     ("requests_submitted_total", "counter", "Requests submitted.",
      lambda m: m.requests_submitted),
     ("requests_completed_total", "counter", "Requests completed.",

@@ -16,7 +16,7 @@ from repro.kernels.conv2d import conv2d
 from repro.kernels.flash_attention import flash_attention
 
 ALL_OPS = ("matmul", "brgemm", "batched_matmul", "conv2d",
-           "flash_attention", "flash_attention_bwd")
+           "flash_attention", "flash_attention_bwd", "paged_attention")
 
 
 def _randn(*shape, dtype=jnp.float32, seed=0):
@@ -135,7 +135,7 @@ def test_precedence_end_to_end_numerics(backend, monkeypatch):
 
 
 # --------------------------------------------------------------------------
-# pallas <-> xla parity through the context, all five ops
+# pallas <-> xla parity through the context, every registered op
 # --------------------------------------------------------------------------
 
 def _run_op(op):
@@ -164,6 +164,16 @@ def _run_op(op):
                                         return_residuals=True)
         dy = _randn(1, 2, 32, 16, seed=8)
         return flash_attention_bwd(q, k, v, y, lse, dy, causal=True)
+    if op == "paged_attention":
+        from repro.kernels.paged_attention import paged_attention
+        # 2 slots of 2 pages of 8 (the second page of slot 0 unused),
+        # 2 KV heads of a group of 2, layer 1 of 2
+        tables = jnp.asarray([[3, 4], [0, 2]], jnp.int32)
+        return paged_attention(
+            _randn(2, 4, 16), _randn(2, 5, 2, 16, 8, seed=1),
+            _randn(2, 5, 2, 16, 8, seed=2), tables,
+            jnp.asarray([5, 16], jnp.int32), _randn(2, 2, 16, seed=3),
+            _randn(2, 2, 16, seed=4), layer=1)
     raise AssertionError(op)
 
 

@@ -625,9 +625,10 @@ def _lowered_program(kind, cfg, params):
         step = ts.make_train_step(cfg, ocfg, backend="pallas")
         return jax.jit(step).lower(state, batch)
     eng = ContinuousEngine(cfg, params, PoolConfig(
-        n_slots=2, max_len=MAX_LEN, page_size=8, prefill_chunk=8))
+        n_slots=2, max_len=MAX_LEN, page_size=8, prefill_chunk=8,
+        kv_quant="int8" if kind == "decode_int8" else None))
     pool = eng.pool
-    if kind == "decode":
+    if kind in ("decode", "decode_int8"):
         return eng._decode.lower(
             params, jnp.zeros((2, 1), jnp.int32), pool.data, pool.scales,
             jnp.asarray(pool.page_tables), jnp.asarray(pool.positions))
@@ -641,8 +642,12 @@ def _lowered_program(kind, cfg, params):
 
 
 @pytest.mark.parametrize("kind,scopes", [
+    # a dense pool decodes in place: no page gather or scatter at all
     ("decode", ["embed", "attention", "attention/core", "attention/kv_write",
-                "mlp", "head", "kv_gather", "kv_scatter"]),
+                "mlp", "head"]),
+    ("decode_int8", ["embed", "attention", "attention/core",
+                     "attention/kv_write", "mlp", "head", "kv_gather",
+                     "kv_scatter"]),
     ("chunk", ["embed", "attention", "attention/core", "attention/kv_write",
                "mlp", "head"]),
     ("insert", ["kv_insert"]),
@@ -657,6 +662,9 @@ def test_named_scopes_reach_compiled_op_metadata(dense, kind, scopes):
     paths = _scope_paths(text)
     for scope in scopes:
         assert _has_scope(paths, scope), (scope, sorted(paths)[:20])
+    if kind == "decode":
+        assert not any(_has_scope(paths, sc)
+                       for sc in ("kv_gather", "kv_scatter"))
     if kind == "train":
         # backward ops keep the scopes of the forward they transpose:
         # the fused flash backward and the brgemm's VJP

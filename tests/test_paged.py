@@ -1,8 +1,9 @@
 """Paged KV cache tests: page-allocator invariants under random churn,
 paged-vs-slotted greedy token parity (dense + enc-dec), the slotted
 fallback for non-pageable architectures, chunked-prefill equivalence,
-quantized page storage, preemption under page pressure, and the
-page-aware attention block geometry."""
+quantized page storage, preemption under page pressure, the in-place
+decode path against the gather path, which pools decode in place, and
+the page-aware attention block geometry."""
 import numpy as np
 import pytest
 
@@ -202,6 +203,9 @@ def test_preemption_under_page_pressure_keeps_parity(dense):
     assert eng.metrics.preemptions > 0
     assert out == ref
     assert eng.pool.page_alloc_count == eng.pool.page_free_count
+    # a dense full-precision pool decodes in place on every step
+    assert eng.decode_path == "in_place"
+    assert eng.metrics.decode_steps_in_place == eng.metrics.decode_steps > 0
 
 
 def test_quantized_pages_parity_within_tolerance(dense):
@@ -324,6 +328,91 @@ def test_chunk_must_align_to_page(dense):
         ContinuousEngine(cfg, params,
                          PoolConfig(n_slots=2, max_len=MAX_LEN,
                                     page_size=8, prefill_chunk=12))
+
+
+# ==========================================================================
+# in-place decode against the gather path
+# ==========================================================================
+
+def _paged_state(cfg, seed=0):
+    """A key-major pool of random pages, and three slots' page tables:
+    shuffled pages, a free slot, and room for three more tokens each."""
+    rng = np.random.default_rng(seed)
+    pool = PagedKVCache(cfg, n_slots=3, max_len=MAX_LEN, page_size=PAGE,
+                        n_pages=10)
+    assert pool.in_place
+    data = jax.tree.map(
+        lambda x: jnp.asarray(rng.normal(size=x.shape), x.dtype), pool.data)
+    lengths = np.array([7, 0, 17], np.int32)       # slot 1 is free
+    tables = np.full((3, pool.pages_per_slot), pool.n_pages, np.int32)
+    perm = rng.permutation(pool.n_pages)
+    tables[0, :2], tables[2, :3] = perm[:2], perm[2:5]
+    return pool, data, tables, lengths
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_in_place_decode_matches_gather_path(dense, backend):
+    cfg, params = dense
+    pool, data, tables, lengths = _paged_state(cfg)
+    kw = dict(batch_axes=pool.batch_axes, time_axes=pool.time_axes,
+              page_size=PAGE, backend=backend)
+    gathered = jax.tree.map(api.key_major, data, pool.time_axes)
+    live = lengths > 0
+    rng = np.random.default_rng(1)
+    for _ in range(3):          # slot 0 crosses into its second page
+        tokens = jnp.asarray(rng.integers(0, cfg.vocab, (3, 1)), jnp.int32)
+        pos = jnp.asarray(lengths)
+        logits, data, _ = api.decode_step_paged(
+            params, tokens, cfg, data, jnp.asarray(tables), pos, **kw)
+        want, gathered, _ = api._decode_gather(
+            params, tokens, cfg, gathered, jnp.asarray(tables), pos, **kw)
+        np.testing.assert_allclose(np.asarray(logits)[live],
+                                   np.asarray(want)[live],
+                                   atol=1e-4, rtol=1e-4)
+        lengths = lengths + live
+    # every live row of the pool agrees, the three new rows included
+    for name in ("k", "v"):
+        got = np.asarray(api.key_major(data["blocks"][name], 3))
+        ref = np.asarray(gathered["blocks"][name])
+        for s in np.nonzero(live)[0]:
+            for p in range(lengths[s]):
+                page = tables[s, p // PAGE]
+                np.testing.assert_allclose(
+                    got[:, page, :, p % PAGE], ref[:, page, :, p % PAGE],
+                    atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("arch,kv_quant,path", [
+    ("smollm-135m", None, "in_place"),
+    ("smollm-135m", "int8", "gather"),
+    ("grok-1-314b", None, "gather"),             # moe
+    ("deepseek-v3-671b", None, "gather"),        # MLA + moe
+    ("seamless-m4t-large-v2", None, "gather"),   # enc-dec
+])
+def test_decode_path_follows_the_pool(arch, kv_quant, path):
+    cfg = configs.get(arch).reduced()
+    params = api.init_params(jax.random.PRNGKey(0), cfg)
+    src_len = SRC_LEN if api.is_encdec(cfg) else 0
+    prompts = _prompts(cfg, [5, 9, 3])
+    rng = np.random.default_rng(3)
+    src = ([jnp.asarray(rng.normal(size=(SRC_LEN, cfg.d_model)),
+                        jnp.float32) for _ in prompts] if src_len else None)
+    eng, out = _serve(cfg, params,
+                      PoolConfig(n_slots=2, max_len=MAX_LEN, src_len=src_len,
+                                 page_size=PAGE, kv_quant=kv_quant),
+                      _requests(prompts, src))
+    assert eng.decode_path == path and eng.pool.in_place == (path ==
+                                                             "in_place")
+    steps = eng.metrics.decode_steps
+    assert steps > 0
+    assert eng.metrics.decode_steps_in_place == (steps if path == "in_place"
+                                                 else 0)
+    if kv_quant is None:        # exact paths: the slotted pool's tokens
+        _, ref = _serve(cfg, params,
+                        PoolConfig(n_slots=2, max_len=MAX_LEN,
+                                   src_len=src_len),
+                        _requests(prompts, src))
+        assert out == ref
 
 
 # ==========================================================================

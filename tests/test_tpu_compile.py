@@ -19,9 +19,12 @@ import repro
 from repro.kernels.brgemm import matmul
 from repro.kernels.flash_attention import flash_attention_bwd
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
+from repro.kernels.paged_attention import paged_attention
 from repro.launch.mesh import make_mesh
 
 HQ, HKV, D, T = 9, 3, 64, 2048     # smollm-135m heads at a 2048 prompt
+# the chat cell's paged pool: 32 slots of 24 pages of 128
+SLOTS, PAGES, PAGE = 32, 24, 128
 
 
 @pytest.fixture(scope="module")
@@ -104,3 +107,61 @@ def test_sharded_matmul_compiles_on_2x2(topo):
         text = _compiled_text(matmul, x, w)
     assert "tpu_custom_call" in text
     assert "all-gather" in text
+
+
+@pytest.mark.parametrize("slots,pages,hq,hkv,d,layers", [
+    (SLOTS, PAGES, HQ, HKV, D, 30),      # smollm-135m chat
+    (8, 65, 56, 8, 128, 6),              # deepseek-coder-33b, 8320 long
+], ids=["smollm", "deepseek-coder"])
+def test_paged_attention_compiles(one_chip, slots, pages, hq, hkv, d,
+                                  layers):
+    pool = _sds((layers, slots * pages, hkv, d, PAGE), one_chip)
+    text = _compiled_text(
+        lambda q, k, v, pt, n, kn, vn, layer: paged_attention(
+            q, k, v, pt, n, kn, vn, layer=layer),
+        _sds((slots, hq, d), one_chip), pool, pool,
+        _sds((slots, pages), one_chip, jnp.int32),
+        _sds((slots,), one_chip, jnp.int32),
+        _sds((slots, hkv, d), one_chip), _sds((slots, hkv, d), one_chip),
+        _sds((), one_chip, jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_paged_decode_donates_the_pool(one_chip):
+    """Two smollm-135m layers on the chat cell's pool: the engine's
+    decode and the pool's insert alias the pool to their output and hold
+    less than the pool in temporaries (no copy of it)."""
+    import dataclasses
+
+    from repro import configs
+    from repro.models import api
+    from repro.serve import ContinuousEngine, PoolConfig
+
+    cfg = dataclasses.replace(configs.get("smollm-135m"), n_layers=2)
+    params = jax.tree.map(
+        lambda x: _sds(x.shape, one_chip, x.dtype),
+        api.params_specs(None, cfg))
+    eng = ContinuousEngine(
+        cfg, params, PoolConfig(n_slots=SLOTS, max_len=PAGES * PAGE,
+                                page_size=PAGE, prefill_chunk=512),
+        backend="pallas", interpret=False)
+    assert eng.decode_path == "in_place"
+    data = jax.tree.map(lambda x: _sds(x.shape, one_chip, x.dtype),
+                        eng.pool.data)
+    pool_bytes = eng.pool.kv_bytes()
+    compiled = eng._decode.lower(
+        params, _sds((SLOTS, 1), one_chip, jnp.int32), data, None,
+        _sds((SLOTS, PAGES), one_chip, jnp.int32),
+        _sds((SLOTS,), one_chip, jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes
+    assert "tpu_custom_call" in compiled.as_text()
+    # the insert of a prefilled request writes its pages where they lie
+    view = jax.tree.map(lambda x: _sds(x.shape, one_chip, x.dtype),
+                        eng.pool.request_cache())
+    mem = eng.pool._insert.lower(
+        data, None, view, _sds((), one_chip, jnp.int32),
+        _sds((PAGES,), one_chip, jnp.int32)).compile().memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes
