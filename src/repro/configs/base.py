@@ -23,6 +23,7 @@ class ArchCfg:
     vocab: int
     head_dim: Optional[int] = None
     rope_theta: float = 10000.0
+    rope_scale: float = 1.0        # linear RoPE scaling: positions / scale
     window: Optional[int] = None   # sliding-window attention size
     # --- MoE ---
     n_experts: int = 0

@@ -68,10 +68,13 @@ def choose_blocks(
 
     Small dims are rounded up to the hardware tile (the wrapper pads), large
     dims get the preferred MXU-friendly block, and bk is shrunk until the
-    double-buffered working set fits the VMEM budget.
+    double-buffered working set fits the VMEM budget.  A weight-streaming
+    GEMM (see :func:`streams_weight`) takes :func:`_streaming_blocks`.
     """
     itemsize = jnp.dtype(dtype).itemsize
     sub = sublane(dtype)
+    if streams_weight(m, n, k):
+        return _streaming_blocks(m, n, k, itemsize, sub, vmem_budget)
 
     bm = min(round_up(m, sub), prefer_bm)
     bm = round_up(bm, sub)
@@ -82,6 +85,41 @@ def choose_blocks(
         bk = max(LANE, bk // 2)
     while gemm_working_set(bm, bn, bk, itemsize) > vmem_budget and bm > sub:
         bm = max(sub, bm // 2)
+    return Blocks(bm=bm, bn=bn, bk=bk)
+
+
+# A GEMM of at most STREAM_ROWS rows against a weight at least STREAM_DIM
+# wide in both dims (a wide model's prefill chunk or decode step) spends
+# its time streaming the weight.  128 x 128 tiles would re-read each weight
+# panel once per 128 rows and take thousands of short grid steps.
+STREAM_ROWS = 512
+STREAM_DIM = 1024
+STREAM_PANEL = 1024
+
+
+def streams_weight(m: int, n: int, k: int) -> bool:
+    return m <= STREAM_ROWS and min(n, k) >= STREAM_DIM
+
+
+def divisor_block(dim: int, cap: int) -> int:
+    """The largest multiple of LANE, at most ``cap``, that divides ``dim``
+    rounded up to LANE: a block that tiles the dim with no padding."""
+    units = round_up(dim, LANE) // LANE
+    return LANE * max(u for u in range(1, max(cap // LANE, 1) + 1)
+                      if units % u == 0)
+
+
+def _streaming_blocks(m, n, k, itemsize, sub, vmem_budget) -> Blocks:
+    """Every row in one block, so each weight panel is read once, and
+    panels up to STREAM_PANEL wide that divide the weight's dims, so the
+    weight is never padded (bk shrinks first to fit VMEM)."""
+    bm = round_up(m, sub)
+    bn = divisor_block(n, STREAM_PANEL)
+    bk = divisor_block(k, STREAM_PANEL)
+    while gemm_working_set(bm, bn, bk, itemsize) > vmem_budget and bk > LANE:
+        bk = divisor_block(k, bk - LANE)
+    while gemm_working_set(bm, bn, bk, itemsize) > vmem_budget and bn > LANE:
+        bn = divisor_block(n, bn - LANE)
     return Blocks(bm=bm, bn=bn, bk=bk)
 
 
@@ -212,6 +250,16 @@ def choose_attention_blocks(
     return AttnBlocks(block_q=min(round_up(tq, 8), 128),
                       block_k=_page_clamp(min(round_up(tk, LANE), LANE),
                                           geometry))
+
+
+def chunk_attention_blocks(tq: int, tk: int) -> AttnBlocks:
+    """Flash attention for a prefill chunk at an offset into a cache: all
+    the chunk's queries in one block (they share the live key extent),
+    keys in blocks of up to 640 rows that divide the cache, so the blocks
+    past the extent cost one skipped grid step each and nothing is
+    padded (8,320 keys = 13 blocks of 640)."""
+    return AttnBlocks(block_q=round_up(tq, 8),
+                      block_k=divisor_block(tk, 5 * LANE))
 
 
 def choose_attention_bwd_blocks(

@@ -7,6 +7,7 @@ and ``repro.core.dispatch`` for the backend registry, the ``repro.use``
 execution context, and the resolution precedence.
 """
 from repro.kernels.brgemm import (  # noqa: F401
+    LayerWeight,
     batched_matmul,
     batched_matmul_q,
     brgemm,

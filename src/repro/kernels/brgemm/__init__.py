@@ -1,4 +1,5 @@
 from repro.kernels.brgemm.ops import (  # noqa: F401
+    LayerWeight,
     batched_matmul,
     brgemm,
     matmul,

@@ -127,28 +127,40 @@ def matmul_pallas(
     blocks: Blocks | None = None,
     interpret: bool = False,
     acc_dtype=jnp.float32,
+    layer=None,
 ):
     """C = act(alpha * X @ W + beta * C0 + bias); X: (m,k), W: (k,n).
 
     The K dimension is the batch-reduce axis: the grid walks K blocks while
     the fp32 accumulator stays resident in VMEM.
+
+    With ``layer`` (an int32 scalar), W is a layer stack (L, k, n) whose
+    blocks must tile (k, n) exactly: the weight panels are read from layer
+    ``layer`` in place (a scalar-prefetched index in the index_map) rather
+    than from a copy of that layer.
     """
     m, k = x.shape
-    k2, n = w.shape
+    k2, n = w.shape[-2:]
     assert k == k2, (x.shape, w.shape)
     out_dtype = out_dtype or x.dtype
     blk = blocks or choose_blocks(m, n, k, x.dtype)
     bm, bn, bk = blk.astuple()
 
     xp = _pad2(x, bm, bk)
-    wp = _pad2(w, bk, bn)
+    if layer is None:
+        wp = _pad2(w, bk, bn)
+        w_spec = pl.BlockSpec((bk, bn), lambda i, j, r: (r, j))
+    else:
+        assert k % bk == 0 and n % bn == 0, (w.shape, blk)
+        wp = w
+        w_spec = pl.BlockSpec((None, bk, bn), lambda i, j, r: (0, r, j))
     mp, kp = xp.shape
-    _, np_ = wp.shape
+    np_ = wp.shape[-1]
     grid = (mp // bm, np_ // bn, kp // bk)
 
     in_specs = [
         pl.BlockSpec((bm, bk), lambda i, j, r: (i, r)),
-        pl.BlockSpec((bk, bn), lambda i, j, r: (r, j)),
+        w_spec,
     ]
     operands = [xp, wp]
     has_c0 = c0 is not None and beta != 0.0
@@ -166,19 +178,45 @@ def matmul_pallas(
         beta=beta, activation=activation, out_dtype=out_dtype,
         block_rank3=False, acc_dtype=acc_dtype,
     )
+    out_spec = pl.BlockSpec((bm, bn), lambda i, j, r: (i, j))
+    scratch = [pltpu.VMEM((bm, bn), acc_dtype)]
+    if layer is None:
+        spec = dict(grid=grid, in_specs=in_specs, out_specs=out_spec,
+                    scratch_shapes=scratch)
+    else:
+        spec = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid,
+            in_specs=[_with_layer(s, i == 1) for i, s in enumerate(in_specs)],
+            out_specs=_with_layer(out_spec, False), scratch_shapes=scratch))
+        operands = [jnp.reshape(layer, (1,)).astype(jnp.int32), *operands]
+        body = _skip_layer_ref(body)
     out = pl.pallas_call(
         body,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, r: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        **spec,
     )(*operands)
     return out[:m, :n]
+
+
+def _with_layer(spec, stacked: bool):
+    """``spec`` under a grid spec whose index maps also receive the
+    scalar-prefetched layer ref; a ``stacked`` operand's block index
+    takes that layer on its leading axis."""
+    imap = spec.index_map
+    if stacked:
+        return pl.BlockSpec(spec.block_shape,
+                            lambda *a: (a[-1][0],) + imap(*a[:-1])[1:])
+    return pl.BlockSpec(spec.block_shape, lambda *a: imap(*a[:-1]))
+
+
+def _skip_layer_ref(body):
+    """The kernel body with the scalar-prefetched layer ref (first) left
+    out: the index maps alone use it."""
+    return lambda layer_ref, *refs: body(*refs)
 
 
 @functools.partial(

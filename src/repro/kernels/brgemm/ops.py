@@ -62,6 +62,30 @@ class _Cfg(NamedTuple):
     acc_dtype: object
 
 
+class LayerWeight:
+    """Layer ``layer`` (an int32 scalar) of a layer-stacked weight ``stack``
+    (L, k, n), left in its stack.  ``matmul`` on the Pallas backend reads
+    the layer's panels where they lie; elsewhere, or where its blocks
+    would pad the weight, it takes the layer out first (a copy).  Serving
+    layer scans pass GEMM weights this way: a weight sliced out of the
+    scanned stack is copied before every Pallas call."""
+
+    def __init__(self, stack, layer):
+        self.stack, self.layer = stack, layer
+
+    @property
+    def shape(self):
+        return self.stack.shape[1:]
+
+    @property
+    def dtype(self):
+        return self.stack.dtype
+
+    def take(self):
+        return jax.lax.dynamic_index_in_dim(self.stack, self.layer,
+                                            keepdims=False)
+
+
 def _make_cfg(op, m, n, k, dtype, activation, alpha, beta, out_dtype,
               blocks) -> _Cfg:
     """Resolve context-dependent knobs (trace-time) into a hashable config."""
@@ -131,6 +155,16 @@ def _matmul_pallas_backend(x, w, bias, c0, *, activation, alpha, beta,
     n = w.shape[-1]
     cfg = _make_cfg("matmul", m, n, k, x.dtype, activation, alpha, beta,
                     out_dtype, blocks)
+    if isinstance(w, LayerWeight):
+        ctx = dispatch.current_context()
+        if (ctx.mesh is None and k % cfg.blocks.bk == 0
+                and n % cfg.blocks.bn == 0):
+            return K.matmul_pallas(
+                x, w.stack, bias, c0, activation=cfg.activation,
+                alpha=cfg.alpha, beta=cfg.beta, out_dtype=cfg.out_dtype,
+                blocks=cfg.blocks, interpret=cfg.interpret,
+                acc_dtype=cfg.acc_dtype, layer=w.layer)
+        w = w.take()
     fn = _local.per_shard_gemm(
         functools.partial(_matmul_p, cfg), "matmul", m, n, k,
         dispatch.current_context(),
@@ -143,6 +177,8 @@ def _matmul_pallas_backend(x, w, bias, c0, *, activation, alpha, beta,
 def _matmul_xla_backend(x, w, bias, c0, *, activation, alpha, beta,
                         out_dtype, blocks):
     del blocks  # tiling is an XLA-internal decision on this path
+    if isinstance(w, LayerWeight):
+        w = w.take()
     return R.matmul_ref(
         x, w, bias, activation=activation, alpha=alpha, beta=beta, c0=c0,
         out_dtype=out_dtype, acc_dtype=dispatch.resolve_accum_dtype())
@@ -172,6 +208,8 @@ def matmul(
     """
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
+    if isinstance(w, LayerWeight) and Q.active_quant(None, quant):
+        w = w.take()
     qcfg = Q.active_quant(w, quant)
     if qcfg is not None and quant is None and c0 is not None and beta != 0.0:
         # Accumulator-chained GEMMs (LSTM gates) have no quantized form;

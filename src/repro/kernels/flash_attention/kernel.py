@@ -45,8 +45,15 @@ def flash_attention_pallas(
     interpret: bool = False,
     acc_dtype=jnp.float32,
     return_residuals: bool = False,
+    q_offset=None,
 ):
     """q: (B, Hq, Tq, d); k, v: (B, Hkv, Tk, d) -> (B, Hq, Tq, d).
+
+    ``q_offset`` (a traced int32 scalar, causal only) places the queries
+    at positions ``q_offset .. q_offset + Tq - 1`` of the keys: a prefill
+    chunk against a cache.  Keys past a query block's last position are
+    neither fetched (their block index repeats the last live one) nor
+    scored, so the cost follows the live extent, not ``Tk``.
 
     Tile geometry comes from ``blocks`` (an ``AttnBlocks``); when unset it
     resolves through ``dispatch.resolve_blocks`` under the active block
@@ -79,8 +86,11 @@ def flash_attention_pallas(
 
     grid = (b, hq, tqp // bq, tkp // bk)
     nkv = tkp // bk
+    offset = q_offset is not None
+    assert causal or not offset, "q_offset places causal chunk queries"
 
-    def body(q_ref, k_ref, v_ref, o_ref, *rest):
+    def body(*refs):
+        q_ref, k_ref, v_ref, o_ref, *rest = refs[1:] if offset else refs
         if return_residuals:
             lse_ref, acc_ref, m_ref, l_ref = rest
         else:
@@ -94,7 +104,7 @@ def flash_attention_pallas(
             l_ref[...] = jnp.zeros_like(l_ref)
 
         i = pl.program_id(2)
-        q_start = i * bq
+        q_start = refs[0][0] + i * bq if offset else i * bq
         k_start = j * bk
 
         def compute():
@@ -148,38 +158,45 @@ def flash_attention_pallas(
                 lse_ref[...] = jnp.broadcast_to(
                     lse, lse_ref.shape[2:])[None, None]
 
-    q_spec = pl.BlockSpec((1, 1, bq, dp), lambda b_, h, i, j: (b_, h, i, 0))
+    def kv_block(b_, h, i, j, *off):
+        if off:   # the last block holding a key this query block sees
+            j = jnp.minimum(j, (off[0][0] + i * bq + bq - 1) // bk)
+        return (b_, h // group, j, 0)
+
+    q_spec = pl.BlockSpec((1, 1, bq, dp),
+                          lambda b_, h, i, j, *_: (b_, h, i, 0))
+    kv_spec = pl.BlockSpec((1, 1, bk, dp), kv_block)
     out_specs = [q_spec]
     out_shape = [jax.ShapeDtypeStruct((b, hq, tqp, dp), q.dtype)]
     if return_residuals:
         out_specs.append(pl.BlockSpec((1, 1, bq, STATS_LANES),
-                                      lambda b_, h, i, j: (b_, h, i, 0)))
+                                      lambda b_, h, i, j, *_: (b_, h, i, 0)))
         out_shape.append(
             jax.ShapeDtypeStruct((b, hq, tqp, STATS_LANES), jnp.float32))
+    scratch = [
+        pltpu.VMEM((bq, dp), acc_dtype),
+        pltpu.VMEM((bq, STATS_LANES), jnp.float32),
+        pltpu.VMEM((bq, STATS_LANES), jnp.float32),
+    ]
+    spec = dict(grid=grid, in_specs=[q_spec, kv_spec, kv_spec],
+                out_specs=out_specs, scratch_shapes=scratch)
+    operands = [qp, kp, vp]
+    if offset:
+        spec = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, **spec))
+        operands = [jnp.reshape(q_offset, (1,)).astype(jnp.int32),
+                    *operands]
 
     outs = pl.pallas_call(
         body,
-        grid=grid,
-        in_specs=[
-            q_spec,
-            pl.BlockSpec((1, 1, bk, dp),
-                         lambda b_, h, i, j: (b_, h // group, j, 0)),
-            pl.BlockSpec((1, 1, bk, dp),
-                         lambda b_, h, i, j: (b_, h // group, j, 0)),
-        ],
-        out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((bq, dp), acc_dtype),
-            pltpu.VMEM((bq, STATS_LANES), jnp.float32),
-            pltpu.VMEM((bq, STATS_LANES), jnp.float32),
-        ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
         interpret=interpret,
-    )(qp, kp, vp)
+        **spec,
+    )(*operands)
     out = outs[0][:, :, :tq, :d]
     if return_residuals:
         return out, outs[1][:, :, :tq, 0]

@@ -24,7 +24,8 @@ import jax
 from jax.sharding import PartitionSpec as P
 
 from repro.core import dispatch
-from repro.core.blocking import AttnBlocks, AttnBwdBlocks
+from repro.core.blocking import (AttnBlocks, AttnBwdBlocks,
+                                  chunk_attention_blocks)
 from repro.kernels.flash_attention import ref as R
 from repro.kernels.flash_attention.bwd import flash_attention_bwd_pallas
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
@@ -117,10 +118,22 @@ def _attention_spec(q, k):
 @dispatch.register("flash_attention", "pallas",
                    available=dispatch.pallas_available, priority=10)
 def _flash_pallas_backend(q, k, v, *, causal, window, scale, xla_impl,
-                          unroll, blocks, blocks_bwd=None):
+                          unroll, blocks, blocks_bwd=None, q_offset=None):
     del xla_impl, unroll  # XLA-path-only knobs
     tq, d = q.shape[-2:]
     tk = k.shape[-2]
+    if q_offset is not None:
+        # a prefill chunk: forward only, its own tiles
+        if _attention_spec(q, k) is not None:
+            return _flash_xla_backend(
+                q, k, v, causal=causal, window=window, scale=scale,
+                xla_impl="naive", unroll=False, blocks=None,
+                q_offset=q_offset)
+        return flash_attention_pallas(
+            q, k, v, causal=causal, window=window, scale=scale,
+            blocks=blocks or chunk_attention_blocks(tq, tk),
+            interpret=dispatch.resolve_interpret(),
+            acc_dtype=dispatch.resolve_accum_dtype(), q_offset=q_offset)
     blk = dispatch.resolve_blocks("flash_attention", tq, tk, d, q.dtype,
                                   backend="pallas", blocks=blocks)
     cfg = _Cfg(causal, window, scale, blk, blocks_bwd,
@@ -135,8 +148,11 @@ def _flash_pallas_backend(q, k, v, *, causal, window, scale, xla_impl,
 
 @dispatch.register("flash_attention", "xla")
 def _flash_xla_backend(q, k, v, *, causal, window, scale, xla_impl, unroll,
-                       blocks, blocks_bwd=None):
+                       blocks, blocks_bwd=None, q_offset=None):
     del blocks, blocks_bwd  # tiling is XLA-internal on this path
+    if q_offset is not None:
+        return R.mha_ref(q, k, v, causal=causal, window=window, scale=scale,
+                         q_offset=q_offset, kv_len=q_offset + q.shape[-2])
     if xla_impl == "chunked":
         return R.mha_chunked(q, k, v, causal=causal, window=window,
                              scale=scale, unroll=unroll)
@@ -199,9 +215,16 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     unroll: bool = False,
                     blocks: AttnBlocks | None = None,
                     blocks_bwd: AttnBwdBlocks | None = None,
-                    block_q: int | None = None, block_k: int | None = None):
+                    block_q: int | None = None, block_k: int | None = None,
+                    q_offset=None):
     """xla_impl: 'naive' (full T^2 softmax) or 'chunked' (online softmax,
     flash semantics — the XLA-path memory optimization).
+
+    ``q_offset`` (causal, forward only) runs a prefill chunk against a
+    cache: the queries sit at positions ``q_offset .. q_offset + Tq - 1``
+    of ``k``/``v`` and see the keys up to their own position.  The Pallas
+    kernel then reads only the blocks holding those keys; the XLA path is
+    the masked reference.
 
     ``blocks`` (an ``AttnBlocks``) is the explicit tier-1 geometry
     override for the forward tile; ``blocks_bwd`` (an ``AttnBwdBlocks``)
@@ -240,6 +263,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
             block_k = block_k if block_k is not None else resolved.block_k
         blocks = AttnBlocks(block_q=block_q, block_k=block_k)
     impl = dispatch.get_impl("flash_attention", backend)
+    # only passed when set: a chunk's offset is an inference-only knob
+    kw = {} if q_offset is None else {"q_offset": q_offset}
     return impl(q, k, v, causal=causal, window=window, scale=scale,
                 xla_impl=xla_impl, unroll=unroll, blocks=blocks,
-                blocks_bwd=blocks_bwd)
+                blocks_bwd=blocks_bwd, **kw)

@@ -45,6 +45,7 @@ class AttnCfg:
     n_kv_heads: int
     head_dim: int | None = None
     rope_theta: float = 10000.0
+    rope_scale: float = 1.0            # linear RoPE position scaling
     window: int | None = None          # sliding-window size (None = full)
     # --- MLA (used when mla=True) ---
     mla: bool = False
@@ -135,8 +136,10 @@ def _gqa_qkv(params, x, cfg, positions, backend):
                      cfg.n_kv_heads)
     v = _split_heads(brgemm.matmul(x, params["wv"], backend=backend),
                      cfg.n_kv_heads)
-    q = apply_rope(q, positions, theta=cfg.rope_theta)
-    k = apply_rope(k, positions, theta=cfg.rope_theta)
+    q = apply_rope(q, positions, theta=cfg.rope_theta,
+                   scale=cfg.rope_scale)
+    k = apply_rope(k, positions, theta=cfg.rope_theta,
+                   scale=cfg.rope_scale)
     return q, k, v
 
 
@@ -166,18 +169,18 @@ def _gqa_prefill_chunk(params, x, cfg, cache, pos, backend):
     """One prompt chunk at absolute positions ``pos .. pos+T-1``.
 
     The chunk's queries see the whole cache causally (earlier chunks plus
-    this one); its K/V land at ``pos``.  Runs on the masked reference
-    attention — the fused kernel has no ``q_offset`` — which is exact, not
-    approximate, so chunked == one-shot prefill holds bit-for-bit on the
-    reference path.
+    this one); its K/V land at ``pos``.  The flash kernel takes ``pos`` as
+    its query offset and reads only the cache blocks the chunk sees; the
+    XLA path runs the masked reference attention, which is exact, so
+    chunked == one-shot prefill holds bit-for-bit there.
     """
     positions = pos + jnp.arange(x.shape[1])
     q, k, v = _gqa_qkv(params, x, cfg, positions, backend)
     cache = _write_cache(cache, {"k": k, "v": v}, (0, 0, pos, 0))
     with jax.named_scope("core"):
-        o = mha_ref(q, cache["k"], cache["v"], causal=True,
-                    window=cfg.window, q_offset=pos,
-                    kv_len=pos + x.shape[1])
+        o = flash_attention(q, cache["k"], cache["v"], causal=True,
+                            window=cfg.window, q_offset=pos,
+                            backend=backend)
     y = brgemm.matmul(_merge_heads(o), params["wo"], backend=backend)
     return y, cache
 
@@ -232,7 +235,8 @@ def _mla_q(params, x, cfg, positions, backend):
     q = q.transpose(0, 2, 1, 3)
     q_nope, q_rope = (q[..., :cfg.qk_nope_dim],
                       q[..., cfg.qk_nope_dim:])
-    q_rope = apply_rope(q_rope, positions, theta=cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, theta=cfg.rope_theta,
+                        scale=cfg.rope_scale)
     return q_nope, q_rope
 
 
@@ -242,7 +246,8 @@ def _mla_compressed_kv(params, x, cfg, positions, backend):
                          ckv_full[..., :cfg.kv_lora_rank])
     k_rope = ckv_full[..., cfg.kv_lora_rank:]          # (B, T, rope)
     k_rope = apply_rope(k_rope[:, None], positions,
-                        theta=cfg.rope_theta)[:, 0]
+                        theta=cfg.rope_theta,
+                        scale=cfg.rope_scale)[:, 0]
     return c_kv, k_rope
 
 

@@ -25,6 +25,7 @@ def attn_cfg(cfg: ArchCfg, *, window=None) -> attention.AttnCfg:
     return attention.AttnCfg(
         d_model=cfg.d_model, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
         head_dim=cfg.head_dim, rope_theta=cfg.rope_theta,
+        rope_scale=cfg.rope_scale,
         window=window if window is not None else cfg.window,
         mla=cfg.mla, q_lora_rank=cfg.q_lora_rank,
         kv_lora_rank=cfg.kv_lora_rank, qk_nope_dim=cfg.qk_nope_dim,

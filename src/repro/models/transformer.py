@@ -154,15 +154,34 @@ def _scan_train(stacked, x, apply_fn, remat, unroll=False):
     return x, aux
 
 
-def _scan_serve(stacked, caches, x, apply_fn, unroll=False):
-    """apply_fn(p, x, cache) -> (x, cache)."""
+def _layer_params(stacked, layer, cfg: ArchCfg):
+    """Layer ``layer`` of a block stack's parameters in a serving scan: the
+    attention and MLP weights stay in their stacks as ``LayerWeight``s,
+    which the Pallas GEMM reads in place (a weight sliced out of the
+    scanned stack is copied before every call: 17 ms a step at
+    deepseek-coder-33b's widths); norms, and the parts of quantized
+    weights, are sliced out."""
+    def pick(path, x):
+        keys = {getattr(k, "key", None) for k in path}
+        if (x.ndim == 3 and keys & {"attn", "mlp"} and not cfg.mla
+                and isinstance(path[-1], jax.tree_util.DictKey)):
+            return brgemm.LayerWeight(x, layer)
+        return jax.lax.dynamic_index_in_dim(x, layer, keepdims=False)
+
+    return jax.tree_util.tree_map_with_path(pick, stacked)
+
+
+def _scan_serve(stacked, caches, x, apply_fn, cfg: ArchCfg):
+    """apply_fn(p, x, cache) -> (x, cache), scanned over layer indices:
+    the stack is a loop constant read through ``_layer_params``."""
 
     def body(x, xs):
-        p, c = xs
-        x, c_new = apply_fn(p, x, c)
-        return x, c_new
+        layer, c = xs
+        return apply_fn(_layer_params(stacked, layer, cfg), x, c)
 
-    return jax.lax.scan(body, x, (stacked, caches), unroll=unroll)
+    n = jax.tree.leaves(stacked)[0].shape[0]
+    return jax.lax.scan(body, x, (jnp.arange(n), caches),
+                        unroll=cfg.scan_unroll)
 
 
 def _run_stacks(params, x, cfg: ArchCfg, *, mode, caches, pos, backend):
@@ -181,7 +200,7 @@ def _run_stacks(params, x, cfg: ArchCfg, *, mode, caches, pos, backend):
             params["blocks"], caches["blocks"], x,
             lambda p, h, c: blocks.decoder_block_apply(
                 p, h, cfg, mode=mode, cache=c, pos=pos, backend=backend)[:2],
-            cfg.scan_unroll)
+            cfg)
         return x, aux, {"blocks": new_c}
 
     if cfg.block == "mla_moe":
@@ -205,12 +224,12 @@ def _run_stacks(params, x, cfg: ArchCfg, *, mode, caches, pos, backend):
             params["dense_blocks"], caches["dense_blocks"], x,
             lambda p, h, c: blocks.decoder_block_apply(
                 p, h, cfg, mode=mode, cache=c, pos=pos, backend=backend)[:2],
-            cfg.scan_unroll)
+            cfg)
         x, c2 = _scan_serve(
             params["moe_blocks"], caches["moe_blocks"], x,
             lambda p, h, c: blocks.decoder_block_apply(
                 p, h, cfg, mode=mode, cache=c, pos=pos, backend=backend)[:2],
-            cfg.scan_unroll)
+            cfg)
         return x, aux, {"dense_blocks": c1, "moe_blocks": c2}
 
     if cfg.block == "xlstm":
@@ -489,16 +508,15 @@ def decode_step_in_place(params, tokens, cfg: ArchCfg, kv, page_tables,
         h = embeddings.encode(params["embed"], tokens).astype(_dt(cfg))
         h = constrain(h, "activation")
 
-    def body(h, xs):
-        p, layer = xs
+    def body(h, layer):
         pages = attention.PagedKV(kv["k"], kv["v"], layer, page_tables,
                                   lengths)
         h, rows, _ = blocks.decoder_block_apply(
-            p, h, cfg, mode="decode_paged", cache=pages, backend=backend)
+            _layer_params(params["blocks"], layer, cfg), h, cfg,
+            mode="decode_paged", cache=pages, backend=backend)
         return h, rows
 
-    h, rows = jax.lax.scan(body, h, (params["blocks"],
-                                     jnp.arange(cfg.n_layers)),
+    h, rows = jax.lax.scan(body, h, jnp.arange(cfg.n_layers),
                            unroll=cfg.scan_unroll)
     logits = _head(params, h, cfg)
     return logits[:, 0], rows

@@ -632,6 +632,10 @@ class ContinuousEngine:
             with span:
                 logits, st["cache"] = chunk_fn(self.params, batch,
                                                st["cache"], jnp.int32(pos))
+                if span is not obs.NULL_SPAN:
+                    # a recorded span times the chunk's device work, as
+                    # ``decode`` does; unrecorded chunks stay async
+                    jax.block_until_ready(logits)
             st["first"] = False
             st["pos"] = pos + width
             self.metrics.prefill_chunks += 1

@@ -109,7 +109,7 @@ def test_param_counts_match_published_scale():
         "grok-1-314b": (314e9, 0.10),
         "starcoder2-15b": (15e9, 0.15),
         "smollm-135m": (135e6, 0.15),
-        "deepseek-coder-33b": (33e9, 0.10),
+        "deepseek-coder-33b": (33.3e9, 0.01),
         "mistral-large-123b": (123e9, 0.10),
         "xlstm-1.3b": (1.3e9, 0.35),
         "llava-next-34b": (34e9, 0.15),
@@ -119,6 +119,20 @@ def test_param_counts_match_published_scale():
         total, _ = configs.get(name).param_counts()
         assert abs(total - target) / target < tol, \
             f"{name}: {total/1e9:.2f}B vs {target/1e9:.2f}B"
+
+
+def test_deepseek_coder_33b_is_the_published_model():
+    """Published config.json: theta 1e5, linear RoPE scaling x4, untied
+    head; 3.64B parameters at the benchmark's 6 of 62 layers."""
+    import dataclasses
+
+    cfg = configs.get("deepseek-coder-33b")
+    assert (cfg.rope_theta, cfg.rope_scale, cfg.tie_embeddings) == (
+        100000.0, 4.0, False)
+    total, _ = dataclasses.replace(cfg, n_layers=6).param_counts()
+    assert total == 6 * (7168 * 128 * (2 * 56 + 2 * 8) + 3 * 7168 * 19200) \
+        + 2 * 32256 * 7168
+    assert round(total / 1e9, 2) == 3.64
 
 
 def test_deepseek_active_params():

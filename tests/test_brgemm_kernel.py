@@ -140,3 +140,49 @@ def test_choose_blocks_vmem_budget():
     ws = (bm * bk + bk * bn) * itemsize * 2 + bm * bn * 4 + bm * bn * itemsize * 2
     assert ws <= 8 * 1024 * 1024
     assert bn % 128 == 0 and bk % 128 == 0 and bm % 16 == 0
+
+
+@pytest.mark.parametrize("k,n,blocks", [
+    (256, 384, Blocks(16, 128, 128)),    # blocks tile the weight: in place
+    (200, 300, Blocks(16, 128, 128)),    # they would pad it: layer taken
+])
+@pytest.mark.parametrize("backend", ["pallas", "xla"])
+def test_layer_weight_matches_its_slice(k, n, blocks, backend):
+    """A LayerWeight multiplies as the layer it names, on both backends."""
+    from repro.kernels.brgemm import LayerWeight
+
+    x = randn(24, k)
+    stack = randn(3, k, n)
+    layer = jnp.int32(2)
+    got = matmul(x, LayerWeight(stack, layer), activation="silu",
+                 backend=backend, blocks=blocks)
+    want = matmul(x, stack[2], activation="silu", backend=backend,
+                  blocks=blocks)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# the code cell's GEMMs (deepseek-coder-33b widths): a 512-row prefill
+# chunk, a 16-slot decode step, the head; and smollm-135m's, which the
+# streaming regime leaves on the 128-wide tiles
+STREAMING = [(512, 19200, 7168), (512, 7168, 19200), (512, 7168, 7168),
+             (512, 1024, 7168), (369, 19200, 7168), (16, 19200, 7168),
+             (16, 7168, 19200), (1, 32256, 7168)]
+SMOLLM = [(512, 1536, 576), (32, 49152, 576), (512, 576, 1536),
+          (32768, 576, 1536), (576, 49152, 32768)]
+
+
+@pytest.mark.parametrize("m,n,k", STREAMING + SMOLLM)
+def test_streaming_blocks_tile_wide_weights(m, n, k):
+    from repro.core.blocking import gemm_working_set, streams_weight
+
+    blk = choose_blocks(m, n, k, jnp.bfloat16)
+    bm, bn, bk = blk.astuple()
+    assert gemm_working_set(bm, bn, bk, 2) <= 8 * 1024 * 1024
+    if (m, n, k) in STREAMING:
+        assert streams_weight(m, n, k)
+        # all rows in one block, the weight read once and never padded
+        assert bm >= m and n % bn == 0 and k % bk == 0
+        assert bn >= 768 and bk >= 512
+    else:
+        assert not streams_weight(m, n, k)
+        assert (bn, bk) == (128, 512) and bm <= 128

@@ -257,6 +257,34 @@ def test_chunked_prefill_matches_one_shot_logits(dense):
                                rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("pos", [0, 100, 250, 368])
+@pytest.mark.parametrize("backend", ["pallas", "xla"])
+def test_chunk_attention_at_an_offset_matches_reference(pos, backend):
+    """A prefill chunk's flash attention at query offset ``pos`` (GQA
+    group 3, several key blocks, the live extent ending mid-block) equals
+    the masked reference over the whole view."""
+    from repro.core.blocking import chunk_attention_blocks
+    from repro.kernels.flash_attention import flash_attention
+    from repro.kernels.flash_attention.ref import mha_ref
+
+    rng = np.random.default_rng(pos)
+    t, tk = 16, 384
+    q = jnp.asarray(rng.normal(size=(1, 6, t, 16)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(1, 2, tk, 16)), jnp.float32)
+            for _ in range(2))
+    # keys past pos + t are stale cache rows the chunk must not see
+    k = k.at[:, :, pos + t:].set(30.0)
+    v = v.at[:, :, pos + t:].set(1e3)
+    assert chunk_attention_blocks(t, tk).block_k == 384
+    want = mha_ref(q, k, v, causal=True, q_offset=pos, kv_len=pos + t)
+    got = jax.jit(lambda q, k, v, p: flash_attention(
+        q, k, v, causal=True, q_offset=p, backend=backend,
+        blocks=blocking.AttnBlocks(block_q=16, block_k=128)))(
+            q, k, v, jnp.int32(pos))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
 def test_chunked_prefill_serving_parity(dense):
     cfg, params = dense
     prompts = _prompts(cfg, PROMPT_LENS)

@@ -165,3 +165,45 @@ def test_paged_decode_donates_the_pool(one_chip):
         _sds((PAGES,), one_chip, jnp.int32)).compile().memory_analysis()
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < pool_bytes
+
+
+def test_code_cell_programs_read_weights_in_place(one_chip):
+    """Two deepseek-coder-33b layers on the code cell's pool: the decode
+    step and a prefill chunk hand each layer's GEMM weights to the kernel
+    inside their stacks, so no layer's weight is copied out of its stack
+    (a (7168, 19200) MLP weight is 275 MB, and was copied for every GEMM
+    of every step)."""
+    import dataclasses
+    import re
+
+    from repro import configs
+    from repro.models import api
+    from repro.serve import ContinuousEngine, PoolConfig
+
+    cfg = dataclasses.replace(configs.get("deepseek-coder-33b"), n_layers=2)
+    params = jax.tree.map(
+        lambda x: _sds(x.shape, one_chip, x.dtype),
+        api.params_specs(None, cfg))
+    slots, pages = 16, 65
+    eng = ContinuousEngine(
+        cfg, params, PoolConfig(n_slots=slots, max_len=pages * PAGE,
+                                page_size=PAGE, prefill_chunk=512),
+        backend="pallas", interpret=False)
+    data = jax.tree.map(lambda x: _sds(x.shape, one_chip, x.dtype),
+                        eng.pool.data)
+    decode = eng._decode.lower(
+        params, _sds((slots, 1), one_chip, jnp.int32), data, None,
+        _sds((slots, pages), one_chip, jnp.int32),
+        _sds((slots,), one_chip, jnp.int32)).compile()
+    view = jax.tree.map(lambda x: _sds(x.shape, one_chip, x.dtype),
+                        eng.pool.request_cache())
+    chunk = eng._chunk_rest.lower(
+        params, {"tokens": _sds((1, 512), one_chip, jnp.int32)}, view,
+        _sds((), one_chip, jnp.int32)).compile()
+    for compiled in (decode, chunk):
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text
+        for shape in ("7168,19200", "19200,7168", "7168,7168", "7168,1024"):
+            assert not re.search(
+                rf"= bf16\[(1,)?{shape}\]\S* (fusion|copy)\(", text), shape
+    assert decode.memory_analysis().temp_size_in_bytes < 64 << 20
