@@ -240,16 +240,82 @@ def _page_clamp(block_k: int, geometry) -> int:
     return min(block_k, geometry.page_size // LANE * LANE)
 
 
+# A flash grid step costs about 0.4 us on a v5e whether it computes or
+# skips its block, ten times the MXU time of a 128 x 128 block, so long
+# sequences take few, large tiles.  Of the tiles 128-1024 square, 1024 x
+# 1024 was the fastest forward and backward on a v5e at 16 x 9 heads x
+# 2048 tokens, head 64: 2.7 and 7.7 ms a layer against 16.7 and 30.6 at
+# 128 x 128 (PERF.md, section 6).
+ATTN_TILE = (1024, 1024)
+# The attention working sets count the score blocks, so they are held to
+# the whole scoped VMEM.  At the train cell's shape that admits every
+# tile up to 1024 x 1024 the compiler took and refuses the backward
+# tiles it refused (bf16 2048 x 512 and 1024 x 2048, float32 1024 x 1024).
+ATTN_VMEM_BUDGET = VMEM_BYTES
+
+
+def attention_working_set(bq: int, bk: int, dp: int, itemsize: int) -> int:
+    """VMEM bytes of one forward tile: q + k + v panels double-buffered,
+    the accumulator and (m, l) statistics, and the fp32 scores block."""
+    panels = (bq * dp + 2 * bk * dp) * itemsize * 2
+    acc = bq * dp * 4 + 2 * bq * LANE * 4
+    return panels + acc + bq * bk * 4
+
+
+def attention_bwd_working_set(bq: int, bk: int, dp: int,
+                              itemsize: int) -> int:
+    """VMEM bytes of one backward tile: q + dy + y panels on the q side
+    (y feeds the fused delta in the dQ kernel) and k + v on the kv side,
+    all double buffered; lse + delta stats rows; dq or dk+dv accumulators
+    (the dk/dv kernel is the larger resident set) plus the dQ kernel's
+    delta accumulator; scores + ds blocks."""
+    panels = (3 * bq * dp + 2 * bk * dp) * itemsize * 2
+    stats = 2 * bq * LANE * 4 * 2
+    accs = 2 * bk * dp * 4 + bq * dp * 4 + bq * LANE * 4
+    return panels + stats + accs + 2 * bq * bk * 4
+
+
+def _even_block(dim: int, cap: int, unit: int, split_unit: int) -> int:
+    """One block over ``dim`` rounded up to ``unit`` when it fits ``cap``;
+    else as few blocks as ``cap`` allows, as even as ``split_unit``
+    allows, so the padding stays under ``split_unit`` a block."""
+    n = -(-dim // cap)
+    return round_up(-(-dim // n), unit if n == 1 else split_unit)
+
+
+def _attention_tile(tq, tk, d, dtype, tile, working_set):
+    """(bq, bk) of at most ``tile`` over a (tq, tk) problem, the key
+    block halved first, then the query block, until it fits VMEM."""
+    itemsize = jnp.dtype(dtype).itemsize
+    dp = round_up(d, LANE)
+    cap_q, cap_k = tile
+    while True:
+        bq = _even_block(tq, cap_q, 8, sublane(dtype))
+        bk = _even_block(tk, cap_k, LANE, LANE)
+        if (working_set(bq, bk, dp, itemsize) <= ATTN_VMEM_BUDGET
+                or cap_q <= LANE and cap_k <= LANE):
+            return bq, bk
+        if cap_k > LANE:
+            cap_k //= 2
+        else:
+            cap_q //= 2
+
+
 def choose_attention_blocks(
     tq: int, tk: int, d: int, dtype=jnp.float32, *, geometry=None
 ) -> AttnBlocks:
     """Static heuristic for flash attention: (tq, tk, d) = (query len,
-    kv len, head dim).  With a ``PagedAttnGeometry``, block_k is clamped
-    so no kv block straddles a page boundary."""
-    del d
-    return AttnBlocks(block_q=min(round_up(tq, 8), 128),
-                      block_k=_page_clamp(min(round_up(tk, LANE), LANE),
-                                          geometry))
+    kv len, head dim).  A dim up to its ``ATTN_TILE`` side is one block,
+    as before; a longer one splits evenly under it.  With a
+    ``PagedAttnGeometry`` (paged decode, where block_k sets the pages a
+    DMA round reads) block_k stays one lane tile, clamped so no kv block
+    straddles a page boundary."""
+    if geometry is not None:
+        return AttnBlocks(block_q=min(round_up(tq, 8), LANE),
+                          block_k=_page_clamp(min(round_up(tk, LANE), LANE),
+                                              geometry))
+    return AttnBlocks(*_attention_tile(tq, tk, d, dtype, ATTN_TILE,
+                                       attention_working_set))
 
 
 def chunk_attention_blocks(tq: int, tk: int) -> AttnBlocks:
@@ -265,10 +331,10 @@ def chunk_attention_blocks(tq: int, tk: int) -> AttnBlocks:
 def choose_attention_bwd_blocks(
     tq: int, tk: int, d: int, dtype=jnp.float32
 ) -> AttnBwdBlocks:
-    """Static heuristic for the flash-attention backward kernels."""
-    del d
-    return AttnBwdBlocks(block_q=min(round_up(tq, 8), 128),
-                         block_k=min(round_up(tk, LANE), LANE))
+    """Static heuristic for the flash-attention backward kernels: as the
+    forward's, under the backward working set."""
+    return AttnBwdBlocks(*_attention_tile(tq, tk, d, dtype, ATTN_TILE,
+                                          attention_bwd_working_set))
 
 
 # --------------------------------------------------------------------------
@@ -348,16 +414,11 @@ def conv_candidates(
 
 def attention_candidates(
     tq: int, tk: int, d: int, dtype=jnp.float32, *,
-    vmem_budget: int = DEFAULT_VMEM_BUDGET,
+    vmem_budget: int = ATTN_VMEM_BUDGET,
     geometry: PagedAttnGeometry | None = None,
 ) -> list[AttnBlocks]:
     itemsize = jnp.dtype(dtype).itemsize
     dp = round_up(d, LANE)
-
-    def working_set(bq, bk):
-        panels = (bq * dp + 2 * bk * dp) * itemsize * 2  # q + k + v
-        acc = bq * dp * 4 + 2 * bq * LANE * 4            # acc + (m, l)
-        return panels + acc + bq * bk * 4                # + scores block
 
     def in_page(bk):
         # paged KV: only kv blocks that evenly tile a page (boundary
@@ -366,13 +427,13 @@ def attention_candidates(
             return True
         return bk <= geometry.page_size and geometry.page_size % bk == 0
 
-    bqs = [b for b in _steps(8, 256) if b <= round_up(tq, 8) or b == 8]
-    bks = [b for b in _steps(LANE, 512)
+    bqs = [b for b in _steps(8, 1024) if b <= round_up(tq, 8) or b == 8]
+    bks = [b for b in _steps(LANE, 1024)
            if (b <= round_up(tk, LANE) or b == LANE) and in_page(b)]
     cands = [
         AttnBlocks(bq, bk)
         for bq in bqs for bk in bks
-        if working_set(bq, bk) <= vmem_budget
+        if attention_working_set(bq, bk, dp, itemsize) <= vmem_budget
     ]
     heur = choose_attention_blocks(tq, tk, d, dtype, geometry=geometry)
     if heur not in cands:
@@ -382,29 +443,17 @@ def attention_candidates(
 
 def attention_bwd_candidates(
     tq: int, tk: int, d: int, dtype=jnp.float32, *,
-    vmem_budget: int = DEFAULT_VMEM_BUDGET,
+    vmem_budget: int = ATTN_VMEM_BUDGET,
 ) -> list[AttnBwdBlocks]:
     itemsize = jnp.dtype(dtype).itemsize
     dp = round_up(d, LANE)
-
-    def working_set(bq, bk):
-        # q + dy + y panels on the q side (y feeds the fused delta in the
-        # dQ kernel), k + v on the kv side, all double buffered; lse +
-        # delta stats rows; dq or dk+dv accumulators (the dk/dv kernel is
-        # the larger resident set) plus the dQ kernel's delta accumulator;
-        # scores + ds blocks.
-        panels = (3 * bq * dp + 2 * bk * dp) * itemsize * 2
-        stats = 2 * bq * LANE * 4 * 2
-        accs = 2 * bk * dp * 4 + bq * dp * 4 + bq * LANE * 4
-        return panels + stats + accs + 2 * bq * bk * 4
-
-    bqs = [b for b in _steps(8, 256) if b <= round_up(tq, 8) or b == 8]
-    bks = [b for b in _steps(LANE, 512)
+    bqs = [b for b in _steps(8, 1024) if b <= round_up(tq, 8) or b == 8]
+    bks = [b for b in _steps(LANE, 1024)
            if b <= round_up(tk, LANE) or b == LANE]
     cands = [
         AttnBwdBlocks(bq, bk)
         for bq in bqs for bk in bks
-        if working_set(bq, bk) <= vmem_budget
+        if attention_bwd_working_set(bq, bk, dp, itemsize) <= vmem_budget
     ]
     heur = choose_attention_bwd_blocks(tq, tk, d, dtype)
     if heur not in cands:

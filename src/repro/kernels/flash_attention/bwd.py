@@ -182,6 +182,16 @@ def flash_attention_bwd_pallas(
         named = {"row": row, "stats": stats}
         return [row, kv, kv, row, stats] + [named[t] for t in tail]
 
+    # Under ``causal`` a skipped block's index repeats the nearest live
+    # one, so the pipeline fetches nothing for it: the dQ kernel's key
+    # blocks stop at the last one its query block sees, the dK/dV
+    # kernel's query blocks start at the first one that sees its keys.
+    def last_live_k(i, j):
+        return jnp.minimum(j, (i * bq + bq - 1) // bk) if causal else j
+
+    def first_live_q(j, i):
+        return jnp.clip(i, (j * bk) // bq, nq - 1) if causal else i
+
     # ---- shared score-block recompute -----------------------------------
 
     def _p_ds(q_ref, k_ref, v_ref, dy_ref, lse_ref, delta_col,
@@ -242,7 +252,7 @@ def flash_attention_bwd_pallas(
     dq, delta = pl.pallas_call(
         dq_body,
         grid=(b, hq, nq, nk),
-        in_specs=_specs(qi=lambda i, j: i, kj=lambda i, j: j,
+        in_specs=_specs(qi=lambda i, j: i, kj=last_live_k,
                         tail=("row",)),
         out_specs=[
             pl.BlockSpec((1, 1, bq, dp),
@@ -302,7 +312,7 @@ def flash_attention_bwd_pallas(
     dk, dv = pl.pallas_call(
         dkdv_body,
         grid=(b, hq, nk, nq),
-        in_specs=_specs(qi=lambda j, i: i, kj=lambda j, i: j,
+        in_specs=_specs(qi=first_live_q, kj=lambda j, i: j,
                         tail=("stats",)),
         out_specs=[
             pl.BlockSpec((1, 1, bk, dp),

@@ -49,11 +49,12 @@ def flash_attention_pallas(
 ):
     """q: (B, Hq, Tq, d); k, v: (B, Hkv, Tk, d) -> (B, Hq, Tq, d).
 
-    ``q_offset`` (a traced int32 scalar, causal only) places the queries
-    at positions ``q_offset .. q_offset + Tq - 1`` of the keys: a prefill
-    chunk against a cache.  Keys past a query block's last position are
+    Under ``causal``, key blocks past a query block's last position are
     neither fetched (their block index repeats the last live one) nor
-    scored, so the cost follows the live extent, not ``Tk``.
+    scored.  ``q_offset`` (a traced int32 scalar, causal only) places the
+    queries at positions ``q_offset .. q_offset + Tq - 1`` of the keys: a
+    prefill chunk against a cache, whose cost so follows the live extent,
+    not ``Tk``.
 
     Tile geometry comes from ``blocks`` (an ``AttnBlocks``); when unset it
     resolves through ``dispatch.resolve_blocks`` under the active block
@@ -159,8 +160,9 @@ def flash_attention_pallas(
                     lse, lse_ref.shape[2:])[None, None]
 
     def kv_block(b_, h, i, j, *off):
-        if off:   # the last block holding a key this query block sees
-            j = jnp.minimum(j, (off[0][0] + i * bq + bq - 1) // bk)
+        if causal:  # a skipped block repeats the last live one: no fetch
+            j = jnp.minimum(
+                j, ((off[0][0] if off else 0) + i * bq + bq - 1) // bk)
         return (b_, h // group, j, 0)
 
     q_spec = pl.BlockSpec((1, 1, bq, dp),

@@ -48,15 +48,36 @@ def _grads(backend, q, k, v, dy_w, **kw):
 # gradient parity: Pallas-fused vs XLA autodiff
 # --------------------------------------------------------------------------
 
+# Several blocks with a partial last one (600 = 2 x 256 + 88), GQA so
+# q-heads share each clamped kv block; the default tiles split 1100 into
+# 2 x 552 queries and 2 x 640 keys.  Short queries against long keys
+# clamp the dK/dV kernel's first live q block to the last one.
+TILED = dict(tq=600, tk=600, hq=4, hkv=2)
+
+
 @pytest.mark.parametrize("name,kw,shape", [
     ("causal", dict(causal=True), dict()),
     ("windowed", dict(causal=True, window=24), dict()),
     ("noncausal", dict(causal=False), dict()),
     ("noncausal_ragged", dict(causal=False), dict(tq=40, tk=72)),
     ("gqa", dict(causal=True), dict(hq=4, hkv=2)),
+    ("causal_tiles", dict(causal=True, blocks=AttnBlocks(256, 512),
+                          blocks_bwd=AttnBwdBlocks(256, 512)), TILED),
+    ("windowed_tiles", dict(causal=True, window=200,
+                            blocks=AttnBlocks(512, 256),
+                            blocks_bwd=AttnBwdBlocks(512, 256)), TILED),
+    ("causal_default_tiles", dict(causal=True),
+     dict(tq=1100, tk=1100, hq=4, hkv=2)),
+    ("causal_short_queries", dict(causal=True, blocks=AttnBlocks(16, 128),
+                                  blocks_bwd=AttnBwdBlocks(16, 128)),
+     dict(tq=40, tk=300)),
 ])
 def test_grad_parity_f32(name, kw, shape):
     q, k, v = _qkv(**shape)
+    np.testing.assert_allclose(
+        np.asarray(flash_attention(q, k, v, backend="pallas", **kw)),
+        np.asarray(flash_attention(q, k, v, backend="xla", **kw)),
+        rtol=2e-3, atol=2e-3, err_msg=f"{name} y")
     dy_w = _randn(*q.shape, seed=9)
     got = _grads("pallas", q, k, v, dy_w, **kw)
     want = _grads("xla", q, k, v, dy_w, **kw)
@@ -171,6 +192,63 @@ def test_bwd_blocks_resolve_through_own_schema():
     d = blocking.blocks_to_dict(blk)
     assert d["kind"] == "attn_bwd"
     assert blocking.blocks_from_dict(d) == blk
+
+
+@pytest.mark.parametrize("op,tq,tk,want", [
+    # the train cell's 2048 tokens: the fastest tiles of the chip sweep
+    ("flash_attention", 2048, 2048, (1024, 1024)),
+    ("flash_attention_bwd", 2048, 2048, (1024, 1024)),
+    # up to a tile: one block; longer: as few blocks as fit, as even as
+    # the alignment allows
+    ("flash_attention", 600, 600, (600, 640)),
+    ("flash_attention", 1100, 1100, (560, 640)),
+    ("flash_attention_bwd", 1100, 1100, (560, 640)),
+    # up to 128: one block, as before
+    ("flash_attention", 33, 33, (40, 128)),
+    ("flash_attention_bwd", 33, 33, (40, 128)),
+    ("flash_attention", 128, 128, (128, 128)),
+    ("flash_attention_bwd", 128, 128, (128, 128)),
+    ("flash_attention", 100, 64, (104, 128)),
+    ("flash_attention_bwd", 64, 100, (64, 128)),
+])
+def test_attention_tiles_follow_the_shape(op, tq, tk, want):
+    blk = blocking.default_blocks(op, tq, tk, 64, jnp.bfloat16)
+    assert blk.astuple() == want
+    itemsize, dp = 2, 128
+    fits = (blocking.attention_working_set if op == "flash_attention"
+            else blocking.attention_bwd_working_set)
+    assert fits(*want, dp, itemsize) <= blocking.ATTN_VMEM_BUDGET
+
+
+@pytest.mark.parametrize("page_size", [64, 128, 256])
+def test_paged_geometry_keeps_its_clamp(page_size):
+    """Paged decode reads block_k as the pages one DMA round fetches: a
+    lane tile at most, within one page, whatever the kv length."""
+    geom = blocking.PagedAttnGeometry(page_size=page_size, pages=24)
+    blk = blocking.default_blocks("flash_attention", 3, 24 * page_size,
+                                  64, jnp.bfloat16, geometry=geom)
+    assert blk == AttnBlocks(8, 128)
+
+
+@pytest.mark.parametrize("bq,bk,itemsize,fits", [
+    (1024, 1024, 2, True),     # compiled for a v5e at the train shape
+    (2048, 256, 2, True),
+    (2048, 512, 2, False),     # refused by the compiler: VMEM exhausted
+    (1024, 2048, 2, False),
+    (1024, 1024, 4, False),
+])
+def test_bwd_working_set_matches_the_compiler(bq, bk, itemsize, fits):
+    ws = blocking.attention_bwd_working_set(bq, bk, 128, itemsize)
+    assert (ws <= blocking.ATTN_VMEM_BUDGET) == fits
+
+
+def test_candidates_reach_the_heuristic_neighbourhood():
+    for op, cls in (("flash_attention", AttnBlocks),
+                    ("flash_attention_bwd", AttnBwdBlocks)):
+        cands = blocking.candidate_blocks(op, 2048, 2048, 64, jnp.bfloat16)
+        for tile in ((256, 512), (512, 512), (512, 1024), (1024, 512),
+                     (1024, 1024)):
+            assert cls(*tile) in cands, (op, tile)
 
 
 def test_bwd_candidates_deterministic_and_include_heuristic():

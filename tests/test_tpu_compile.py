@@ -98,6 +98,23 @@ def test_flash_backward_compiles(one_chip):
     assert text.count("tpu_custom_call") >= 2
 
 
+def test_train_cell_attention_compiles_with_default_tiles(one_chip):
+    """The train cell's attention (batch 16, 2048 tokens) with the tiles
+    the heuristic gives it: the forward with residuals and the fused
+    backward, so a default tile too large for VMEM is refused here."""
+    q = _sds((16, HQ, T, D), one_chip)
+    kv = _sds((16, HKV, T, D), one_chip)
+    lse = _sds((16, HQ, T), one_chip, jnp.float32)
+    fwd = _compiled_text(
+        lambda q, k, v: flash_attention_pallas(
+            q, k, v, causal=True, return_residuals=True), q, kv, kv)
+    bwd = _compiled_text(
+        lambda q, k, v, y, lse, dy: flash_attention_bwd(
+            q, k, v, y, lse, dy, causal=True), q, kv, kv, q, lse, q)
+    assert "tpu_custom_call" in fwd
+    assert bwd.count("tpu_custom_call") >= 2
+
+
 def test_sharded_matmul_compiles_on_2x2(topo):
     mesh = make_mesh((2, 2), ("data", "model"), devices=topo.devices)
     x = _sds((2048, 576), NamedSharding(mesh, P("data", None)))
